@@ -38,6 +38,9 @@ def main() -> None:
                    help="write a machine-readable per-suite report "
                         "(e.g. BENCH_serving.json)")
     args = p.parse_args()
+    from repro.core.compile_cache import enable_persistent_cache
+
+    enable_persistent_cache()
     only = set(args.only.split(",")) if args.only else None
 
     n_val = 1_000_000 if args.full else 100_000

@@ -11,10 +11,12 @@ import numpy as np
 from repro.core.domains import DOMAINS
 from repro.kernels.domain_map.ops import bb_membership, block_counts, map_coordinates
 from repro.serving import MappingService
+from repro.serving.evaluate import auto_interpret
 
 N = 16_384
 MODEL = "OSS:120b"
 FRACTALS = sorted(n for n, d in DOMAINS.items() if d.kind == "fractal")
+INTERPRET = auto_interpret()  # compiled on a TPU, interpreted elsewhere
 
 svc = MappingService(n_validate=20_000, sample_every=10)
 
@@ -26,11 +28,11 @@ for name in FRACTALS:
     # validation report licenses the registered exact kernel); otherwise
     # fall back to the domain's ground-truth geometry.
     spec = art if art is not None and art.deployable else name
-    coords = map_coordinates(spec, N, interpret=True)
+    coords = map_coordinates(spec, N, interpret=INTERPRET)
     # every mapped point must be inside the domain, no duplicates
     assert dom.contains(coords).all()
     ext = dom.bounding_box_extent(N)
-    mask = bb_membership(spec, ext, interpret=True)
+    mask = bb_membership(spec, ext, interpret=INTERPRET)
     bc = block_counts(spec, N)
     via = "artifact" if spec is art else "ground truth"
     print(f"{dom.paper_name:22s}{N:>8,}{int(np.prod(ext)):>12,}"

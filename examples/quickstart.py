@@ -5,6 +5,8 @@
 3. synthesize + validate the generated map_to_coordinates over 10^5 points,
 4. deploy the derived map as the Pallas attention grid (mapped vs BB).
 
+Kernels run compiled on a TPU and in Pallas interpret mode elsewhere.
+
     PYTHONPATH=src python examples/quickstart.py
 """
 import jax
@@ -15,6 +17,7 @@ from repro.core.domains import DOMAINS
 from repro.core.pipeline import derive_mapping
 from repro.kernels.tri_attn.ops import causal_attention, grid_steps
 from repro.kernels.tri_attn.ref import causal_attention_ref
+from repro.serving.evaluate import auto_interpret
 
 dom = DOMAINS["tri2d"]
 
@@ -35,7 +38,7 @@ assert res.perfect
 print("--- phase 4: integration — the map as the attention grid ---")
 q, k, v = (jax.random.normal(kk, (1, 4, 512, 64), jnp.float32)
            for kk in jax.random.split(jax.random.PRNGKey(0), 3))
-out = causal_attention(q, k, v, 128, 128, "mapped", True)   # interpret=True
+out = causal_attention(q, k, v, 128, 128, "mapped", auto_interpret())
 err = float(jnp.max(jnp.abs(out - causal_attention_ref(q, k, v))))
 bb, mp = grid_steps(512, 128, "bounding_box"), grid_steps(512, 128, "mapped")
 print(f"kernel err vs oracle: {err:.2e}")

@@ -9,7 +9,7 @@ from __future__ import annotations
 import dataclasses
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     arch_id: str
     family: str                     # dense | moe | ssm | hybrid | audio | vlm

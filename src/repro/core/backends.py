@@ -619,15 +619,18 @@ def canonical_code(domain: str) -> str:
 class EngineBackend:
     """LLMBackend over the in-repo batched serving engine (`serving/engine`).
 
-    This is the 'real backend' wiring: a smoke-config transformer runs true
-    prefill + step-wise decode over the (byte-tokenized) Appendix-A prompt —
+    This is the 'real backend' wiring: a transformer runs true prefill +
+    step-wise decode over the (byte-tokenized) Appendix-A prompt —
     deterministic because params come from a fixed seed and decoding is
-    greedy by default.  The smoke model is untrained, so its sampled text
-    essentially never synthesizes into a valid ``map_to_coordinates``; when
-    synthesis of the sampled text fails, the backend falls back to the
-    canonical derivation for the requested domain, exactly as the mock's
-    extension path does — so the pipeline downstream (synthesis, validation,
-    artifact publish) always exercises its real code path, while the
+    greedy by default.  ``config`` is the model it runs; the default is the
+    arch's smoke config (CPU tests), and ``repro.launch.serve`` passes the
+    published config unless ``--smoke``.  The model is untrained, so its
+    sampled text essentially never synthesizes into a valid
+    ``map_to_coordinates``; when synthesis of the sampled text fails, the
+    backend falls back to the canonical derivation for the requested
+    domain, exactly as the mock's extension path does — so the pipeline
+    downstream (synthesis, validation, artifact publish) always exercises
+    its real code path, while the
     inference cost (wall seconds, modeled joules) is *measured* from the
     actual prefill/decode run rather than replayed from priors.
 
@@ -640,9 +643,13 @@ class EngineBackend:
     def __init__(self, model: str, arch: str = "yi-6b",
                  prompt_tokens: int = 48, max_new_tokens: int = 16,
                  temperature: float = 0.0, seed: int = 0,
-                 power_w: float | None = None):
+                 power_w: float | None = None, config=None):
+        from repro.configs import get_smoke_config
+
         self.name = model
-        self.arch = arch
+        self.config = config if config is not None \
+            else get_smoke_config(arch)
+        self.arch = self.config.arch_id
         self.prompt_tokens = prompt_tokens
         self.max_new_tokens = max_new_tokens
         self.temperature = temperature
@@ -656,8 +663,13 @@ class EngineBackend:
     def cache_fingerprint(self) -> str:
         """Engine cells must never collide with mock cells for the same
         (domain, model, stage): the fingerprint carries the backend kind,
-        the arch + decode knobs, and the canonical-fallback bank hash."""
-        knobs = (self.arch, self.prompt_tokens, self.max_new_tokens,
+        the arch + its widths (a smoke-engine artifact never serves a
+        full-width request), the decode knobs, and the canonical-fallback
+        bank hash."""
+        c = self.config
+        widths = (c.n_layers, c.d_model, c.n_heads, c.n_kv_heads,
+                  c.head_dim, c.d_ff, c.vocab_size, c.dtype)
+        knobs = (self.arch, widths, self.prompt_tokens, self.max_new_tokens,
                  self.temperature, self.seed)
         return f"engine:{knobs!r}:{replay_bank_fingerprint()}"
 
@@ -665,10 +677,9 @@ class EngineBackend:
         if self._engine is None:
             import jax
 
-            from repro.configs import get_smoke_config
             from repro.models import transformer as T
 
-            cfg = get_smoke_config(self.arch).replace(
+            cfg = self.config.replace(
                 max_seq=self.prompt_tokens + self.max_new_tokens)
             params = T.init_params(jax.random.PRNGKey(0), cfg)
             self._engine = (params, cfg)
@@ -716,7 +727,7 @@ class EngineBackend:
             try:
                 synthesis.synthesize(text)
             except synthesis.SynthesisError:
-                # the smoke model can't derive maps — fall back to the
+                # the untrained model can't derive maps — fall back to the
                 # canonical derivation so downstream stages stay live
                 text = f"```python\n{canonical_code(meta['domain'])}```"
             out.append(LLMResponse(

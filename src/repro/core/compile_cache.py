@@ -1,4 +1,6 @@
-"""CompileCache — process-wide cache of compiled map executables.
+"""CompileCache — process-wide cache of compiled map executables, plus the
+one switch for JAX's own persistent compilation cache
+(:func:`enable_persistent_cache`).
 
 The deployment-side economics of the paper (Sec. V.C: the mapped kernel's
 4833x speedup) only materialize if a deployed map runs at hardware speed on
@@ -34,6 +36,8 @@ Env surface (read by :func:`default_compile_cache`, overridable from
 
     REPRO_COMPILE_CACHE_ENTRIES   LRU capacity (default 128; 0 disables)
     REPRO_COMPILE_CACHE_DIR       on-disk persistence root (default: off)
+    JAX_COMPILATION_CACHE_DIR     JAX's persistent cache (default:
+                                  ``<checkout>/.jax_cache``)
 """
 from __future__ import annotations
 
@@ -52,14 +56,41 @@ DEFAULT_MAX_ENTRIES = 128
 #: sentinel: "use the process-default cache" (None = bypass caching)
 USE_DEFAULT = object()
 
+#: JAX's persistent compilation cache when JAX_COMPILATION_CACHE_DIR is
+#: unset: a fixed path (the path is part of every entry's key, so a
+#: directory that moves never hits)
+JAX_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_persistent_cache() -> str:
+    """Point JAX's persistent compilation cache at one fixed directory and
+    return it — called at the start of every entry point's ``main``.
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and
+    nothing else is set; otherwise the cache is :data:`JAX_CACHE_DIR`."""
+    import jax
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(JAX_CACHE_DIR))
+    return str(JAX_CACHE_DIR)
+
+
+def device_info() -> dict:
+    """The devices this process computes on, as JAX reports them — what the
+    serve banner, ``/healthz`` and every chip result name."""
+    import jax
+
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
 
 def device_kind() -> str:
     """The accelerator identity baked into every key — an executable
     compiled for one device kind must never serve another."""
-    import jax
-
-    devs = jax.devices()
-    return f"{devs[0].platform}:{devs[0].device_kind}"
+    dev = device_info()
+    return f"{dev['platform']}:{dev['kind']}"
 
 
 def spec_fingerprint(spec) -> str:

@@ -14,7 +14,7 @@ import threading
 from typing import Any
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # logical axis -> mesh axis (str), tuple of mesh axes, or None (replicated)
 PARAM_RULES: dict[str, Any] = {
@@ -49,6 +49,15 @@ ACT_RULES_SP: dict[str, Any] = {
     "kv_seq": "data",
     "seq": None,
 }
+
+
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
+    """A device mesh whose axes are all Auto.  The models place activations
+    with sharding hints (:func:`logical_constraint`) and leave the rest to
+    the partitioner, which only Auto axes allow: on JAX's default Explicit
+    axes every op's sharding is part of its type, and a gather or an add of
+    differently sharded operands is a type error."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 @dataclasses.dataclass

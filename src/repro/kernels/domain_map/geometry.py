@@ -42,7 +42,10 @@ def _tri_xy(lam):
 
 
 def _tet_z(lam):
-    z = jnp.cbrt(6.0 * lam.astype(jnp.float32)).astype(jnp.int32)
+    """Largest z with T(z) = z(z+1)(z+2)/6 <= lam.  The fp32 cube-root seed
+    is exp(log(6 lam) / 3): Mosaic has no ``cbrt``; the ladder makes it
+    exact."""
+    z = jnp.exp(jnp.log(6.0 * lam.astype(jnp.float32)) / 3.0).astype(jnp.int32)
     for _ in range(3):
         z = jnp.where((z + 1) * (z + 2) * (z + 3) // 6 <= lam, z + 1, z)
         z = jnp.where((z > 0) & (z * (z + 1) * (z + 2) // 6 > lam), z - 1, z)

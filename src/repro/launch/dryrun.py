@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 This is the proof that the distribution config is coherent without real
@@ -12,9 +9,14 @@ roofline analysis (launch/roofline.py) consumes.
 Usage:
     python -m repro.launch.dryrun --arch yi-6b --shape train_4k
     python -m repro.launch.dryrun --all [--multi-pod both] --out results/dryrun
+
+The model path sets ``XLA_FLAGS`` for the 512 placeholder devices in
+``main``, before JAX first touches a backend; ``--domain-map`` runs on
+whatever device JAX finds.
 """
-import argparse  # noqa: E402
+import argparse
 import json
+import os
 import time
 import traceback
 
@@ -154,15 +156,16 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
 
 def run_domain_map_cell(artifact, n_points: int = 65_536,
-                        block_n: int = 1024, interpret: bool = True,
-                        verbose: bool = True) -> dict:
+                        block_n: int = 1024, verbose: bool = True) -> dict:
     """Deploy a validated ``MappingArtifact`` through the mapped-grid Pallas
     kernel and verify the compiled coordinates against the artifact's own
     validated scalar map — the Phase-4 integration proof for one artifact."""
     import numpy as np
 
     from repro.kernels.domain_map.ops import block_counts, map_coordinates
+    from repro.serving.evaluate import auto_interpret
 
+    interpret = auto_interpret()
     t0 = time.time()
     coords = map_coordinates(artifact, n_points, block_n=block_n,
                              interpret=interpret)
@@ -175,7 +178,7 @@ def run_domain_map_cell(artifact, n_points: int = 65_536,
         "domain": artifact.domain, "model": artifact.model,
         "stage": artifact.stage, "logic": artifact.logic,
         "report_digest": artifact.report_digest,
-        "n_points": n_points, "block_n": block_n,
+        "n_points": n_points, "block_n": block_n, "interpret": interpret,
         "kernel_s": round(t_run, 3),
         "blocks": block_counts(artifact, n_points, block_n),
         "analytic": analytic.artifact_deployment_analytics(artifact),
@@ -232,6 +235,8 @@ def main() -> None:
         _run_domain_map(args.domain_map, args.map_model, args.out)
         return
 
+    # 512 placeholder host devices back the production meshes
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     cells: list[tuple[str, str]] = []
     if args.all:
         cells = [(a, s) for a in ARCH_IDS for s in SHAPES]

@@ -8,12 +8,14 @@ from __future__ import annotations
 
 import jax
 
+from repro.distribution.sharding import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod; multi_pod adds a leading 2-pod axis (512)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(data: int | None = None, model: int = 1):
@@ -21,4 +23,4 @@ def make_local_mesh(data: int | None = None, model: int = 1):
     n = jax.device_count()
     if data is None:
         data = n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

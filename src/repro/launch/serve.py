@@ -11,8 +11,9 @@ Networked mapping service (HTTP frontend over a MappingService):
         --backend engine --port 8000 --max-batch 8 --max-wait 0.01
 
 ``--backend`` picks the inference backend behind the service: ``mock``
-(paper replay bank), ``engine`` (real prefill/decode on the in-repo smoke
-transformer — see ``core/backends.EngineBackend``), or ``ollama`` (live
+(paper replay bank), ``engine`` (real prefill/decode on the in-repo
+transformer at ``--arch``'s published widths, or its smoke config with
+``--smoke`` — see ``core/backends.EngineBackend``), or ``ollama`` (live
 local GGUF models).  Derive requests for the same model are admitted
 through a batching queue (``--max-batch`` / ``--max-wait`` /
 ``--max-pending``); same-cell requests coalesce inside the service.
@@ -26,9 +27,19 @@ threaded ``ThreadingHTTPServer`` + gather-then-drain batching.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import functools
 import os
 import time
+
+
+def engine_config(args):
+    """The model the engine backend runs: ``--arch``'s published config, or
+    its smoke config with ``--smoke``."""
+    from repro.configs import get_config, get_smoke_config
+
+    arch = args.arch or "yi-6b"
+    return get_smoke_config(arch) if args.smoke else get_config(arch)
 
 
 def _backend_factory(args):
@@ -38,7 +49,7 @@ def _backend_factory(args):
         return backends.MockLLMBackend
     if args.backend == "engine":
         return functools.partial(
-            backends.EngineBackend, arch=args.arch or "yi-6b",
+            backends.EngineBackend, config=engine_config(args),
             max_new_tokens=args.max_new, temperature=args.temperature)
     if args.backend == "ollama":
         return backends.OllamaBackend
@@ -139,9 +150,48 @@ def _router_from_args(args):
     )
 
 
-def serve_maps(args) -> None:
-    """Boot the full stack: backend -> batching queue -> MappingService ->
-    HTTP frontend (-> cluster membership), then serve until interrupted.
+@dataclasses.dataclass
+class MapServer:
+    """One built serving stack: backend -> batcher -> MappingService -> HTTP
+    frontend (-> cluster membership).  The async frontend is already bound
+    and its loop running; the threaded one serves from ``serve_forever``."""
+
+    args: argparse.Namespace
+    service: object
+    server: object
+    router: object
+    cluster: object
+    compile_cache: object
+    wire_entries: int
+    serve_delay: float
+
+    @property
+    def url(self) -> str:
+        return self.server.url
+
+    def serve_forever(self) -> None:
+        try:
+            self.server.serve_forever()
+        except KeyboardInterrupt:
+            pass
+        finally:
+            self.close()
+
+    def close(self) -> None:
+        if self.cluster is not None:
+            self.cluster.close()
+        if self.args.use_async:
+            self.server.close()
+        else:
+            self.server.httpd.server_close()
+        for backend in self.service.backends().values():
+            close = getattr(backend, "close", None)
+            if close is not None:
+                close()
+
+
+def build_map_server(args) -> MapServer:
+    """Build the full stack from parsed CLI args (see :func:`build_parser`).
 
     ``--async`` (the default) serves from the asyncio event-loop frontend
     and, for the engine backend, drives generation through the continuous
@@ -195,7 +245,16 @@ def serve_maps(args) -> None:
                                    router=router, serve_delay=serve_delay,
                                    wire_cache_entries=wire_entries)
     cluster = _cluster_from_args(args, server)
-    store = service.store
+    return MapServer(args, service, server, router, cluster, cc,
+                     wire_entries, serve_delay)
+
+
+def print_banner(stack: MapServer) -> None:
+    from repro.core.compile_cache import device_info
+
+    args, server, router = stack.args, stack.server, stack.router
+    cluster, cc = stack.cluster, stack.compile_cache
+    store = stack.service.store
     if store is None:
         desc = "off"
     else:
@@ -208,6 +267,14 @@ def serve_maps(args) -> None:
     mode = "async" if args.use_async else "threaded"
     print(f"mapping service on {server.url}  "
           f"(backend={args.backend}, frontend={mode}, store={desc})")
+    dev = device_info()
+    print(f"device: platform={dev['platform']} kind={dev['kind']} "
+          f"count={dev['count']}")
+    if args.backend == "engine":
+        cfg = engine_config(args)
+        print(f"engine model: {cfg.arch_id} layers={cfg.n_layers} "
+              f"d_model={cfg.d_model} "
+              f"({'smoke' if args.smoke else 'published'} config)")
     print(f"observability: tracing={'on' if args.observability else 'off'} "
           f"(X-Repro-Trace-Id; GET /v1/trace/<id>), metrics=json+prometheus "
           f"(GET /metrics?format=prometheus)")
@@ -221,7 +288,7 @@ def serve_maps(args) -> None:
               f"persist={cc.persist_dir or 'off'}")
     print(f"evaluate wire: binary framing via 'Accept: "
           f"application/x-repro-binary' or ?format=binary, "
-          f"response LRU={wire_entries} entries")
+          f"response LRU={stack.wire_entries} entries")
     if cluster is not None:
         print(f"cluster: self={cluster.self_url} replicas="
               f"{cluster.replicas} vnodes={cluster.vnodes} "
@@ -232,25 +299,21 @@ def serve_maps(args) -> None:
               f"peers_up={cluster.live_peers() or 'none'}")
     print(f"router: policy={router.policy} epsilon={router.selector.epsilon} "
           f"ttl={router.queue.ttl}s max_pending={router.queue.capacity}")
-    if serve_delay > 0:
+    if stack.serve_delay > 0:
         print(f"CHAOS: --slow-serve active, every derive sleeps "
-              f"{serve_delay}s before serving")
+              f"{stack.serve_delay}s before serving")
     print("endpoints: POST /v1/derive  POST /v1/evaluate  "
           "GET|DELETE /v1/artifact/<key>  "
           "POST /v1/grid  GET /v1/store/stats  GET /v1/cluster  "
           "GET /v1/replicate/manifest  GET|POST /v1/replicate/<key>  "
           "GET /v1/trace/<id>  GET /v1/traces  GET /healthz  GET /metrics")
-    try:
-        server.serve_forever()
-    except KeyboardInterrupt:
-        pass
-    finally:
-        if cluster is not None:
-            cluster.close()
-        if args.use_async:
-            server.close()
-        else:
-            server.httpd.server_close()
+
+
+def serve_maps(args) -> None:
+    """Build the stack, print its banner, then serve until interrupted."""
+    stack = build_map_server(args)
+    print_banner(stack)
+    stack.serve_forever()
 
 
 def lm_demo(args) -> None:
@@ -288,12 +351,14 @@ def lm_demo(args) -> None:
     print("sample:", res.tokens[0, args.prompt_len:args.prompt_len + 16].tolist())
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default=None,
                    help="model arch (LM demo; also the engine backend's "
-                        "smoke config, default yi-6b)")
-    p.add_argument("--smoke", action="store_true")
+                        "model, default yi-6b)")
+    p.add_argument("--smoke", action="store_true",
+                   help="run --arch's reduced smoke config instead of its "
+                        "published widths (LM demo and engine backend)")
     p.add_argument("--batch", type=int, default=4)
     p.add_argument("--prompt-len", type=int, default=32)
     p.add_argument("--max-new", type=int, default=32)
@@ -425,8 +490,15 @@ def main() -> None:
                         "derive — makes this replica artificially slow so "
                         "load-aware routing can be demonstrated "
                         "[REPRO_SLOW_SERVE]")
-    args = p.parse_args()
+    return p
 
+
+def main() -> None:
+    from repro.core.compile_cache import enable_persistent_cache
+
+    p = build_parser()
+    args = p.parse_args()
+    enable_persistent_cache()
     if args.serve_maps:
         serve_maps(args)
     else:

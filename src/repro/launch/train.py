@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.core.compile_cache import enable_persistent_cache
 from repro.distribution import sharding as shd
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.models import transformer as T
@@ -62,6 +63,7 @@ def main() -> None:
     p.add_argument("--n-layers", type=int, default=0)
     p.add_argument("--d-ff", type=int, default=0)
     args = p.parse_args()
+    enable_persistent_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     over = {"max_seq": args.seq}

@@ -18,13 +18,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-try:  # public since jax 0.5 (replication check kwarg renamed to check_vma)
-    from jax import shard_map as _shard_map
-    _SHARD_MAP_NOCHECK = {"check_vma": False}
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_NOCHECK = {"check_rep": False}
-
 from repro.distribution.sharding import logical_constraint as lc
 from repro.models.common import CAP, EMBED, EXPERTS, FFN, dense_init, mlp_init, mlp_specs
 
@@ -347,11 +340,10 @@ def moe_apply_a2a(p, cfg, x, mesh, with_aux: bool = False,
     tok_spec = P(axes_present if axes_present else None, None)
     w_e_spec = P(None, model_axis, None)
     down_spec = P(None, model_axis, None)
-    out_fn = _shard_map(
+    out_fn = jax.shard_map(
         device_fn, mesh=mesh,
         in_specs=(tok_spec, P(None, None), w_e_spec, w_e_spec, down_spec),
-        out_specs=(tok_spec, P()),
-        **_SHARD_MAP_NOCHECK,
+        out_specs=(tok_spec, P()), check_vma=False,
     )
     out, aux = out_fn(tokens, p["router"], p["gate"], p["up"], p["down"])
     out = out.reshape(b, s, d)

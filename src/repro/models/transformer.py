@@ -22,6 +22,7 @@ cache-less (training) and cached (serving) paths share one scan body.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -508,7 +509,13 @@ def _whisper_logits(params, cfg, x):
 # ===========================================================================
 
 
+@functools.partial(jax.jit, static_argnums=1)
 def init_params(key, cfg):
+    """Random parameters for ``cfg``, built in one jitted program: its peak
+    is the parameter bytes.  Run eagerly, the f32 samples of ``dense_init``
+    and the per-layer arrays ``stack_layers`` copies from sit beside the
+    result, about twice the bytes (a 32-layer 6B model would not fit one
+    16 GB chip)."""
     dtype = _dtype(cfg)
     if cfg.family in ("dense", "moe", "vlm"):
         k1, k2, k3 = jax.random.split(key, 3)

@@ -42,6 +42,7 @@ import urllib.request
 from repro.core import pipeline
 from repro.core import store as store_mod
 from repro.core.backends import LLMBusyError
+from repro.core.compile_cache import device_info
 from repro.core.domains import DOMAINS
 from repro.obs import Observability
 from repro.obs import trace as obs_trace
@@ -554,6 +555,7 @@ class AsyncMappingHTTPServer:
             "backend_names": sorted(self.service.backends()),
             # advertised load, same numbers the cluster view piggybacks
             "load": self.router.load(),
+            "device": device_info(),
         }
         if self.cluster is not None:
             payload["cluster_nodes_up"] = len(self.cluster.live_peers()) + 1

@@ -82,7 +82,10 @@ class EngineStepper:
         self._fns = None
         self._mu = threading.Lock()
 
-    def _ensure(self):
+    def model(self):
+        """``(params, cfg, prefill, step)`` — built once, on first use: the
+        backend's params plus the jitted prefill and decode step every
+        cohort shares."""
         with self._mu:
             if self._fns is None:
                 import jax
@@ -101,7 +104,7 @@ class EngineStepper:
 
         from repro.serving.engine import greedy
 
-        params, cfg, prefill, _ = self._ensure()
+        params, cfg, prefill, _ = self.model()
         b = self.backend
         toks = np.stack([b._tokenize(p, cfg.vocab_size) for p in prompts])
         t0 = time.monotonic()
@@ -117,7 +120,7 @@ class EngineStepper:
 
         from repro.serving.engine import greedy
 
-        params, cfg, _, step = self._ensure()
+        params, cfg, _, step = self.model()
         b = self.backend
         state.generated.append(state.tok)
         sub = None

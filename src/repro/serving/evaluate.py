@@ -59,8 +59,10 @@ TIERS = ("map", "membership")
 
 
 def auto_interpret() -> bool:
-    """Pallas lowers natively on TPU/GPU; anywhere else (CPU CI, tests)
-    the kernels run in interpret mode."""
+    """The one place interpret mode is decided: Pallas lowers natively on
+    TPU/GPU; anywhere else (CPU CI, tests) the kernels run in interpret
+    mode.  Every evaluate result reports the choice (``interpret``), so a
+    process that silently landed on the CPU is visible on the wire."""
     import jax
 
     return jax.default_backend() not in ("tpu", "gpu")
@@ -388,7 +390,6 @@ class EvaluationService:
         executable (tier ``map_sharded``)."""
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         if name not in DOMAINS:
@@ -408,7 +409,7 @@ class EvaluationService:
 
             def run():
                 lams = jnp.arange(padded, dtype=jnp.int32)
-                return shard_map(
+                return jax.shard_map(
                     lambda l: fn(l, ndigits),
                     mesh=mesh, in_specs=P("d"), out_specs=P("d"))(lams)
 
@@ -420,7 +421,11 @@ class EvaluationService:
             call = self.cache.get(key, build)
         else:
             call = build()
-        coords = np.asarray(call())[:n_points]
+        out = call()
+        # devices the output actually lives on — a program that collapsed
+        # onto one device would still report ``devices: n_dev``
+        shard_devices = len(out.sharding.device_set)
+        coords = np.asarray(out)[:n_points]
         with self._mu:
             self.stats.queries += 1
             self.stats.sharded_dispatches += 1
@@ -432,7 +437,7 @@ class EvaluationService:
             "block_n": 0, "ndigits": ndigits, "padded": padded,
             "interpret": False, "group": 0, "group_size": 1,
             "executable": "sharded", "devices": n_dev,
-            "coords": coords,
+            "shard_devices": shard_devices, "coords": coords,
         }
 
     # -- introspection -----------------------------------------------------

@@ -29,7 +29,8 @@ sharing across machines means a network surface.  This module wraps a
                                 triggers our own peer fetch)
     POST   /v1/replicate/<key>  replication push: store a record published
                                 by a sibling server into the local tiers
-    GET    /healthz             liveness probe (+ uptime, serving mode)
+    GET    /healthz             liveness probe (+ uptime, serving mode,
+                                device platform/kind/count)
     GET    /metrics             ServiceStats + per-endpoint latency
                                 percentiles + batching/admission counters +
                                 per-tier store counters + cluster state
@@ -79,6 +80,7 @@ from repro.core.backends import (
     LLMTimeoutError,
     LLMUnavailableError,
 )
+from repro.core.compile_cache import device_info
 from repro.core.domains import DOMAINS
 from repro.obs import Observability
 from repro.obs import trace as obs_trace
@@ -500,6 +502,7 @@ def _make_handler(server: MappingHTTPServer):
                 # piggybacks) — lets external LBs and siblings read queue
                 # depth off the liveness probe
                 "load": server.router.load(),
+                "device": device_info(),
             }
             if server.cluster is not None:
                 payload["cluster_nodes_up"] = \

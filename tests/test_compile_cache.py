@@ -279,3 +279,31 @@ def test_malformed_env_value_warns_and_falls_back(monkeypatch,
 def test_spec_fingerprint_identities():
     assert cc.spec_fingerprint("tri2d") == "domain:tri2d"
     assert cc.spec_fingerprint(DOMAINS["gasket2d"]) == "domain:gasket2d"
+
+
+@pytest.mark.parametrize("env", [None, "elsewhere"])
+def test_persistent_cache_is_env_dir_or_fixed_checkout_path(
+        monkeypatch, tmp_path, env):
+    """JAX_COMPILATION_CACHE_DIR, when set, is JAX's and nothing else is
+    set; otherwise the cache is <checkout>/.jax_cache, the same path on
+    every call."""
+    import pathlib
+
+    import jax
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if env is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = str(pathlib.Path(__file__).resolve().parents[1]
+                       / ".jax_cache")
+            assert cc.enable_persistent_cache() == want
+            assert cc.enable_persistent_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                               str(tmp_path / env))
+            assert cc.enable_persistent_cache() == str(tmp_path / env)
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -19,16 +19,16 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 def test_resolve_spec_basic():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = shd.make_mesh((1, 1), ("data", "model"))
     spec = shd.resolve_spec(("embed", "ffn"), shd.PARAM_RULES, mesh,
                             (64, 128))
     assert spec == P("data", "model")
 
 
 def test_resolve_spec_divisibility_fallback():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = shd.make_mesh((1, 1), ("data", "model"))
     # simulate a 16-wide axis via rules on a fake mesh: use shape-aware check
-    mesh16 = jax.make_mesh((1,), ("model",))
+    mesh16 = shd.make_mesh((1,), ("model",))
     # 24 heads % 1 == 0 -> sharded; emulate non-divisible with explicit size
     spec = shd.resolve_spec(("heads",), {"heads": "model"}, mesh16, (24,))
     assert spec == P("model")
@@ -38,7 +38,7 @@ def test_resolve_spec_divisibility_fallback():
 
 
 def test_resolve_spec_duplicate_axis_dropped():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = shd.make_mesh((1, 1), ("data", "model"))
     spec = shd.resolve_spec(("ffn", "ffn"), shd.PARAM_RULES, mesh, (8, 8))
     assert spec == P("model")  # second use of the mesh axis is dropped
 
@@ -85,7 +85,7 @@ _SUBPROCESS_SCRIPT = textwrap.dedent("""
     single_p, single_s, single_m = jax.jit(make_train_step(cfg, tcfg))(
         params, opt_state, batch)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = shd.make_mesh((4, 2), ("data", "model"))
     with shd.use_sharding(mesh):
         p_sh = shd.param_sharding(T.param_specs(cfg), params, mesh)
         params_d = jax.device_put(params, p_sh)
@@ -107,7 +107,7 @@ _SUBPROCESS_SCRIPT = textwrap.dedent("""
     # ---- 2. elastic checkpoint reshard: (4,2) -> (2,4) ------------------
     d = "/tmp/elastic_ck"
     ckpt.save(d, 3, sp, ss)
-    mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh2 = shd.make_mesh((2, 4), ("data", "model"))
     with shd.use_sharding(mesh2):
         p_sh2 = shd.param_sharding(T.param_specs(cfg), params, mesh2)
         o_sh2 = shd.param_sharding(opt.state_specs(T.param_specs(cfg)),
@@ -121,15 +121,11 @@ _SUBPROCESS_SCRIPT = textwrap.dedent("""
     print("OK elastic-reshard")
 
     # ---- 3. compressed gradient all-reduce ------------------------------
-    mesh1d = jax.make_mesh((8,), ("pod",))
+    mesh1d = shd.make_mesh((8,), ("pod",))
     rng = np.random.default_rng(1)
     local = jnp.asarray(rng.standard_normal((8, 512)), jnp.float32)
 
-    try:
-        from jax import shard_map            # jax >= 0.5
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    @partial(shard_map, mesh=mesh1d, in_specs=P("pod"),
+    @partial(jax.shard_map, mesh=mesh1d, in_specs=P("pod"),
              out_specs=P("pod"))
     def reduce_fn(x):
         return compressed_psum_mean(x, "pod", 8)

@@ -121,3 +121,52 @@ def test_engine_backend_cache_identity_distinct_from_mock(engine_backend):
     # stable across instances with the same knobs
     twin = EngineBackend("OSS:120b", max_new_tokens=4)
     assert twin.cache_fingerprint == engine_backend.cache_fingerprint
+
+
+def test_engine_backend_runs_its_config_and_keys_on_widths(engine_backend):
+    """The default is the smoke config; a published-width engine occupies
+    its own content addresses, so a smoke artifact never serves it."""
+    from repro.configs import get_config, get_smoke_config
+
+    assert engine_backend.config == get_smoke_config("yi-6b")
+    full = EngineBackend("OSS:120b", max_new_tokens=4,
+                         config=get_config("yi-6b"))
+    assert full.config.d_model == 4096 and full.config.n_layers == 32
+    assert full.cache_fingerprint != engine_backend.cache_fingerprint
+    assert "4096" in full.cache_fingerprint
+
+
+def test_serve_cli_builds_the_stack_and_reports_its_device(tmp_path,
+                                                           monkeypatch):
+    """``build_map_server`` takes ``main()``'s parsed args: ``--backend
+    engine`` without ``--smoke`` means published widths; the built async
+    server answers on an ephemeral port and /healthz names the device."""
+    import jax
+
+    from repro.launch import serve
+    from repro.serving import RemoteMappingService
+
+    parser = serve.build_parser()
+    full = parser.parse_args(["--serve-maps", "--backend", "engine",
+                              "--arch", "yi-6b"])
+    assert serve.engine_config(full).d_model == 4096
+    smoke = parser.parse_args(["--serve-maps", "--backend", "engine",
+                               "--arch", "yi-6b", "--smoke"])
+    assert serve.engine_config(smoke).d_model == 64
+
+    monkeypatch.setenv("REPRO_ARTIFACT_CACHE", str(tmp_path))
+    args = parser.parse_args(["--serve-maps", "--port", "0",
+                              "--n-validate", "2000"])
+    stack = serve.build_map_server(args)
+    try:
+        client = RemoteMappingService(stack.url)
+        assert client.healthy()
+        health = client._call_json("/healthz", None)
+        dev = jax.devices()
+        assert health["device"] == {"platform": dev[0].platform,
+                                    "kind": dev[0].device_kind,
+                                    "count": len(dev)}
+        res = client.derive("tri2d", "OSS:120b", 20)
+        assert res.perfect
+    finally:
+        stack.close()

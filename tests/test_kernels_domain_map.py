@@ -45,3 +45,23 @@ def test_block_counts_paper_scale():
     bc = block_counts("tri2d", 500_000_000)
     assert bc["mapped_steps"] == 1_953_125
     assert bc["waste_fraction"] > 0.4
+
+
+def test_pyramid3d_kernel_seed_exact_at_every_tetrahedral_boundary():
+    """The in-kernel tetrahedral-root seed (exp/log, since Mosaic has no
+    cbrt) plus its correction ladder lands on the numpy tier's coordinates
+    at every layer boundary T(z)-1, T(z), T(z)+1 up to 2^21."""
+    import jax.numpy as jnp
+
+    from repro.core.maps import np_map
+    from repro.kernels.domain_map.geometry import pyramid3d_coords
+
+    z = np.arange(0, 400, dtype=np.int64)
+    tet = z * (z + 1) * (z + 2) // 6
+    lams = np.unique(np.concatenate([tet - 1, tet, tet + 1]))
+    lams = lams[(lams >= 0) & (lams <= 1 << 21)]
+    assert lams[-1] > (1 << 21) - 25_000  # the last layer below 2^21 is in
+    got = np.stack([np.asarray(a) for a in pyramid3d_coords(
+        jnp.asarray(lams, jnp.int32), 0)], axis=-1)
+    np.testing.assert_array_equal(got.astype(np.int64),
+                                  np_map("pyramid3d", lams))

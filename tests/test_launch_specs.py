@@ -45,7 +45,7 @@ def test_input_specs_trace_on_tiny_mesh(arch, shape_name):
     import dataclasses
 
     shape = dataclasses.replace(shape, seq_len=256, global_batch=4)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = shd.make_mesh((1, 1), ("data", "model"))
     with shd.use_sharding(mesh):
         fn, args, donate = input_specs(cfg, shape, mesh)
         out = jax.eval_shape(fn, *args)  # abstract eval only

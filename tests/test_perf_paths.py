@@ -127,7 +127,7 @@ def test_moe_a2a_subprocess():
         from repro.models import moe as M
         from repro.distribution import sharding as shd
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = shd.make_mesh((2, 4), ("data", "model"))
         cfg = SimpleNamespace(d_model=32, n_experts=8, moe_top_k=2,
                               expert_d_ff=64, n_shared_experts=1,
                               capacity_factor=8.0, moe_renormalize=True,
