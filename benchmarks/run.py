@@ -33,7 +33,7 @@ def main() -> None:
     p.add_argument("--only", default=None,
                    help="comma-separated subset of: accuracy|fig5|dense"
                         "|fractal|attn|msimplex|serving|cluster|routing"
-                        "|evaluate|wire|concurrency|observability|loadgen")
+                        "|evaluate|wire|concurrency|loadgen")
     p.add_argument("--json", default=None, metavar="PATH",
                    help="write a machine-readable per-suite report "
                         "(e.g. BENCH_serving.json)")
@@ -67,7 +67,6 @@ def main() -> None:
         "evaluate": serving.evaluate_suite,
         "wire": serving.wire_suite,
         "concurrency": serving.concurrency_suite,
-        "observability": serving.observability_suite,
         "loadgen": serving.loadgen_suite,
     }
     report: dict = {"suites": {}, "args": {"full": args.full}}
@@ -99,7 +98,6 @@ def main() -> None:
                                  or "evaluate" in report["suites"]
                                  or "wire" in report["suites"]
                                  or "concurrency" in report["suites"]
-                                 or "observability" in report["suites"]
                                  or "loadgen" in report["suites"]):
         report["serving"] = serving.LAST_METRICS
         # the serving suite runs against its own private store, invisible to

@@ -78,14 +78,6 @@ replica scheduler against the static ring-order baseline:
     a fixed keyset on a 5-node fleet for both placements (max/ideal and
     min/ideal recorded for each; a number, not an assertion).
 
-The observability sub-suite (``--only observability``) measures what the
-PR 8 tracing plane costs on the async hot path: hot-derive p50 with
-request tracing enabled vs disabled (metrics stay on in both — only span
-recording + trace-ID propagation differ).  Acceptance: tracing adds at
-most 5% to the hot-derive p50 (plus a small absolute jitter floor, since
-a hot derive is tens of microseconds and scheduler noise alone exceeds
-5% of that).
-
 Run metrics (cache hits, coalescing, p50/p95 from the server's own
 /metrics, per-tier store counters) land in ``LAST_METRICS`` so ``run.py
 --json`` can emit them.
@@ -907,53 +899,6 @@ def concurrency_suite(levels=(16, 64, 256), total: int = 2048) -> dict:
     return results
 
 
-def observability_suite(n_conns: int = 8, per_conn: int = 160,
-                        repeats: int = 3) -> dict:
-    """Instrumentation overhead on the async hot path: hot-derive p50 with
-    request tracing on vs off.  Interleaved A/B repeats, best-of-N per arm,
-    so a background hiccup can't land entirely on one side."""
-    header("serving: observability overhead (async hot derive, "
-           "tracing on vs off)")
-    kw = dict(n_validate=20_000, sample_every=10)
-    best = {True: float("inf"), False: float("inf")}
-    rows = {}
-    for _ in range(repeats):
-        for enabled in (True, False):
-            cache = ArtifactCache(tempfile.mkdtemp(prefix="bench_obs_"))
-            factory = batching_factory(MockLLMBackend, max_batch=8,
-                                       max_wait=0.005)
-            service = MappingService(cache=cache, backend_factory=factory,
-                                     **kw)
-            with AsyncMappingHTTPServer(service,
-                                        observability=enabled) as server:
-                RemoteMappingService(server.url).derive("tri2d", MODEL, 100)
-                row = _hammer(server, n_conns, per_conn)
-            if row["p50_us"] < best[enabled]:
-                best[enabled] = row["p50_us"]
-                rows[enabled] = row
-    p50_on, p50_off = best[True], best[False]
-    overhead = p50_on / p50_off - 1.0
-    results = {
-        "tracing_on": rows[True],
-        "tracing_off": rows[False],
-        "p50_on_us": p50_on,
-        "p50_off_us": p50_off,
-        "overhead_frac": overhead,
-    }
-    emit("observability_on_hot_p50", p50_on, f"{overhead * 100:+.1f}%")
-    emit("observability_off_hot_p50", p50_off, "baseline")
-    LAST_METRICS["observability"] = results
-    print(f"(hot derive p50: tracing on {p50_on:.0f}us vs off "
-          f"{p50_off:.0f}us = {overhead * 100:+.1f}% overhead)")
-    # acceptance: tracing costs <= 5% of the hot-path p50, with a 25us
-    # absolute floor — at tens-of-us latencies, scheduler jitter alone can
-    # exceed a pure percentage bound
-    assert p50_on <= p50_off * 1.05 + 25.0, (
-        f"observability overhead too high: p50 {p50_on:.1f}us with tracing "
-        f"vs {p50_off:.1f}us without (bound: 5% + 25us)")
-    return results
-
-
 def loadgen_suite(requests: int = 200, concurrency: int = 8) -> dict:
     """Zipf trace replay against a self-hosted 2-node async fleet — the SLO
     harness exercised end to end (see ``benchmarks/loadgen.py``)."""
@@ -986,5 +931,4 @@ if __name__ == "__main__":
     cluster_suite()
     evaluate_suite()
     concurrency_suite()
-    observability_suite()
     loadgen_suite()

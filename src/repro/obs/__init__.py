@@ -8,12 +8,13 @@ measurement surfaces this package provides:
 * Prometheus text exposition of the same numbers
   (``GET /metrics?format=prometheus``);
 * per-request traces (``X-Repro-Trace-Id``) in a bounded ring buffer,
-  served by ``GET /v1/trace/<id>`` and ``GET /v1/traces``.
+  served by ``GET /v1/trace/<id>`` and ``GET /v1/traces``; every span
+  also opens a ``repro:<span>`` profiler annotation (once jax is loaded)
+  and lands in the process-wide ring ``trace.spans_between`` reads.
 
 ``enabled=False`` turns request *tracing* off (no ID generation, no
-contextvar activation, no span records) while metrics keep flowing — the
-knob behind ``--no-observability`` and the instrumentation-overhead
-benchmark.
+contextvar activation, no span records in any sink) while metrics keep
+flowing — the knob behind ``--no-observability``.
 """
 from __future__ import annotations
 

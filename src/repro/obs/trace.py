@@ -27,6 +27,22 @@ the stack:
   ``meta[META_KEY]`` (in-process only — the tuple is never serialized) and
   the worker calls :func:`record_for_meta` against it.
 
+Every span recorded goes to three sinks:
+
+* the request's :class:`TraceBuffer` (``GET /v1/trace/<id>``);
+* a ``jax.profiler.TraceAnnotation`` named ``repro:<span>``, open for the
+  span's length on the thread doing the work, which puts the program's
+  spans on the profiler's clock beside the device's ops in any capture
+  (only once ``jax`` has been imported: this module never imports it, and
+  a span recorded after the fact by :func:`record_span` or
+  :func:`record_for_meta` has none);
+* a process-wide bounded ring of recent finished spans, ``(name, t0, t1,
+  cpu_s, attrs)`` on ``time.monotonic``, read by :func:`spans_between`.
+  ``cpu_s`` is the recording thread's CPU time over the span
+  (``time.thread_time``; None for a span recorded after the fact): wall
+  minus CPU on a pure-host span is time spent waiting for the interpreter
+  lock or the scheduler.  The ring outlives any one server.
+
 Everything here is a no-op (one contextvar read) when no trace is active,
 which is what keeps the hot path's instrumentation overhead in the noise.
 """
@@ -34,6 +50,7 @@ from __future__ import annotations
 
 import collections
 import contextvars
+import sys
 import threading
 import time
 import uuid
@@ -126,6 +143,54 @@ class TraceBuffer:
 
 
 # ---------------------------------------------------------------------------
+# Process-wide span ring + profiler annotations
+# ---------------------------------------------------------------------------
+
+#: finished spans the ring holds; the oldest fall off past this
+SPAN_RING_SIZE = 1 << 16
+
+#: prefix of the program's profiler annotations
+ANNOTATION_PREFIX = "repro:"
+
+_ring: collections.deque = collections.deque(maxlen=SPAN_RING_SIZE)
+_ring_mu = threading.Lock()
+_annotation_cls = None
+
+
+def _ring_add(name: str, t0: float, t1: float, cpu_s: float | None,
+              attrs: dict) -> None:
+    with _ring_mu:
+        _ring.append((name, t0, t1, cpu_s, attrs))
+
+
+def spans_between(t0: float, t1: float, name: str | None = None) -> list:
+    """The ring's spans ``(name, t0, t1, cpu_s, attrs)`` that ended in
+    ``[t0, t1)`` (``time.monotonic``), oldest first; only ``name``'s when
+    given."""
+    with _ring_mu:
+        held = list(_ring)
+    return [s for s in held
+            if t0 <= s[2] < t1 and (name is None or s[0] == name)]
+
+
+def _annotation(name: str):
+    """An entered ``repro:<name>`` profiler annotation, or None while
+    ``jax`` has not been imported (by anyone: this module never does)."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        jax = sys.modules.get("jax")
+        cls = getattr(getattr(jax, "profiler", None), "TraceAnnotation",
+                      None)
+        if cls is None:
+            return None
+        _annotation_cls = cls
+    ann = cls(ANNOTATION_PREFIX + name)
+    ann.__enter__()
+    return ann
+
+
+# ---------------------------------------------------------------------------
 # Context propagation + span recording
 # ---------------------------------------------------------------------------
 
@@ -167,7 +232,8 @@ _NOOP = _NoopSpan()
 
 
 class _LiveSpan:
-    __slots__ = ("_buffer", "_trace_id", "_name", "_attrs", "_t0", "_wall")
+    __slots__ = ("_buffer", "_trace_id", "_name", "_attrs", "_t0", "_wall",
+                 "_cpu", "_ann")
 
     def __init__(self, buffer: TraceBuffer, trace_id: str, name: str,
                  attrs: dict):
@@ -177,17 +243,24 @@ class _LiveSpan:
         self._attrs = attrs
 
     def __enter__(self) -> dict:
-        self._t0 = time.monotonic()
+        self._ann = _annotation(self._name)
         self._wall = time.time()
+        self._cpu = time.thread_time()
+        self._t0 = time.monotonic()
         return self._attrs  # caller may add attrs mid-span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        t1 = time.monotonic()
+        cpu_s = time.thread_time() - self._cpu
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         rec = {"name": self._name, "start_unix": self._wall,
-               "duration_ms": (time.monotonic() - self._t0) * 1e3}
+               "duration_ms": (t1 - self._t0) * 1e3}
         if exc_type is not None:
             rec["error"] = exc_type.__name__
         rec.update(self._attrs)
         self._buffer.record(self._trace_id, rec)
+        _ring_add(self._name, self._t0, t1, cpu_s, self._attrs)
         return False
 
 
@@ -214,13 +287,29 @@ def meta_context() -> dict:
     return {META_KEY: ctx} if ctx is not None else {}
 
 
+def _record_finished(ctx: tuple, name: str, t0: float, t1: float,
+                     attrs: dict) -> None:
+    buffer, trace_id = ctx
+    buffer.record(trace_id, {
+        "name": name, "start_unix": time.time() - (time.monotonic() - t0),
+        "duration_ms": (t1 - t0) * 1e3, **attrs})
+    _ring_add(name, t0, t1, None, attrs)
+
+
+def record_span(name: str, t0: float, t1: float, **attrs) -> None:
+    """Record a span that ran ``[t0, t1)`` (``time.monotonic``) into the
+    active trace, after the fact: a wait in which nothing ran, such as a
+    queue (no-op when no trace is active)."""
+    ctx = _current.get()
+    if ctx is not None:
+        _record_finished(ctx, name, t0, t1, attrs)
+
+
 def record_for_meta(meta: dict, name: str, seconds: float, **attrs) -> None:
     """Record a just-finished span of ``seconds`` against the trace carried
     in ``meta`` (no-op when the request was untraced)."""
     ctx = meta.get(META_KEY)
     if ctx is None:
         return
-    buffer, trace_id = ctx
-    buffer.record(trace_id, {
-        "name": name, "start_unix": time.time() - seconds,
-        "duration_ms": seconds * 1e3, **attrs})
+    t1 = time.monotonic()
+    _record_finished(ctx, name, t1 - seconds, t1, attrs)

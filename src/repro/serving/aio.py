@@ -110,9 +110,11 @@ class _Conn:
         self.responded = True
         # echo the active trace ID (set by _dispatch) back to the caller
         extra = obs_trace.wire_headers() or None
-        self.writer.write(
-            _head(status, content_type, len(body), close, extra) + body)
-        await self.writer.drain()
+        with obs_trace.span("wire_send", bytes=len(body)):
+            self.writer.write(
+                _head(status, content_type, len(body), close, extra) + body)
+        with obs_trace.span("wire_drain"):
+            await self.writer.drain()
 
     async def send_json(self, status: int, payload: dict,
                         close: bool = False) -> None:
@@ -875,10 +877,18 @@ class AsyncMappingHTTPServer:
                     return
         if await self._maybe_forward_evaluate(conn, body, batch, binary):
             return
-        blob = await self._offload(
-            lambda: ev.encoded_batch_response(
+        # the wait in the worker pool: from submit here to the start on a
+        # worker, with the offloads already in flight
+        depth, t_submit = self._pending, time.monotonic()
+
+        def run() -> bytes:
+            obs_trace.record_span("eval_queue", t_submit, time.monotonic(),
+                                  depth=depth)
+            return ev.encoded_batch_response(
                 evaluator, self.eval_wire, batch,
-                single=single, binary=binary))
+                single=single, binary=binary)
+
+        blob = await self._offload(run)
         self._eval_served = True
         await conn.send_bytes(200, blob, content_type=response_type)
 

@@ -50,6 +50,7 @@ from repro.core.domains import DOMAINS, Domain
 from repro.core.registry import REGISTRY
 from repro.core.store import valid_key
 from repro.kernels.domain_map import ops
+from repro.obs import trace as obs_trace
 
 #: hard ceiling on one query's output size — a JSON-serialized answer past
 #: this is a transport problem, not an evaluation problem (use sweeps).
@@ -255,55 +256,69 @@ class EvaluationService:
         Results arrive in query order; each carries its coordinates/mask as
         a numpy array plus grouping/caching provenance.  A malformed query
         fails the whole batch (``ValueError``); an unknown domain or
-        artifact key raises ``KeyError`` — both before any dispatch."""
+        artifact key raises ``KeyError`` — both before any dispatch.
+        Under an active request trace each phase is one span, whatever the
+        batch size: ``eval_plan``, ``eval_dispatch``, ``eval_fetch`` (the
+        wait for the device plus every group's copy to the host) and
+        ``eval_assemble``."""
         if not queries:
             raise ValueError("empty query batch")
-        try:
-            plans = [self._plan(i, q) for i, q in enumerate(queries)]
-        except Exception:
-            with self._mu:
-                self.stats.errors += 1
-            raise
-        groups: dict[tuple, list[_Plan]] = {}
-        for p in plans:
-            groups.setdefault(p.group_key, []).append(p)
+        with obs_trace.span("eval_plan", queries=len(queries)) as sp:
+            try:
+                plans = [self._plan(i, q) for i, q in enumerate(queries)]
+            except Exception:
+                with self._mu:
+                    self.stats.errors += 1
+                raise
+            groups: dict[tuple, list[_Plan]] = {}
+            for p in plans:
+                groups.setdefault(p.group_key, []).append(p)
+            sp["groups"] = len(groups)
 
         # phase 1 — dispatch every group (device work overlaps; no host
         # transfer yet)
         launched = []
-        for members in groups.values():
-            call, padded, ndigits, fresh = self._group_executable(members)
-            launched.append((members, call(), padded, ndigits, fresh))
+        with obs_trace.span("eval_dispatch") as sp:
+            for members in groups.values():
+                call, padded, ndigits, fresh = self._group_executable(members)
+                launched.append((members, call(), padded, ndigits, fresh))
+            sp["fresh"] = sum(1 for *_, fresh in launched if fresh)
 
-        # phase 2 — one transfer per group, then pure-host slicing
+        # phase 2 — one transfer per group (the wait for the device and the
+        # device->host copy), then pure-host slicing
+        with obs_trace.span("eval_fetch") as sp:
+            outs = [np.asarray(out_dev) for _, out_dev, *_ in launched]
+            sp["bytes"] = sum(out.nbytes for out in outs)
         results: list[dict] = [None] * len(plans)  # type: ignore[list-item]
-        for gid, (members, out_dev, padded, ndigits, fresh) in \
-                enumerate(launched):
-            out = np.asarray(out_dev)
-            for p in members:
-                if p.tier == "membership":
-                    # the kernel's int32 0/1 column is logically boolean —
-                    # publish it as bool_ (1 byte/cell on the wire) and let
-                    # the dtype ride the payload so clients round-trip it
-                    data = {"mask": out[0, :p.n_points].astype(np.bool_)}
-                else:
-                    data = {"coords": out[:p.domain.dim, :p.n_points].T}
-                results[p.index] = {
-                    "index": p.index,
-                    "domain": p.domain.name,
-                    "tier": p.tier,
-                    "n_points": p.n_points,
-                    "start": p.start,
-                    "extent": list(p.extent) if p.extent else None,
-                    "block_n": p.block_n,
-                    "ndigits": ndigits,
-                    "padded": padded,
-                    "interpret": p.interpret,
-                    "group": gid,
-                    "group_size": len(members),
-                    "executable": "miss" if fresh else "hit",
-                    **data,
-                }
+        with obs_trace.span("eval_assemble"):
+            for gid, ((members, _, padded, ndigits, fresh), out) in \
+                    enumerate(zip(launched, outs)):
+                for p in members:
+                    if p.tier == "membership":
+                        # the kernel's int32 0/1 column is logically
+                        # boolean — publish it as bool_ (1 byte/cell on the
+                        # wire) and let the dtype ride the payload so
+                        # clients round-trip it
+                        data = {"mask":
+                                out[0, :p.n_points].astype(np.bool_)}
+                    else:
+                        data = {"coords": out[:p.domain.dim, :p.n_points].T}
+                    results[p.index] = {
+                        "index": p.index,
+                        "domain": p.domain.name,
+                        "tier": p.tier,
+                        "n_points": p.n_points,
+                        "start": p.start,
+                        "extent": list(p.extent) if p.extent else None,
+                        "block_n": p.block_n,
+                        "ndigits": ndigits,
+                        "padded": padded,
+                        "interpret": p.interpret,
+                        "group": gid,
+                        "group_size": len(members),
+                        "executable": "miss" if fresh else "hit",
+                        **data,
+                    }
         with self._mu:
             self.stats.queries += len(plans)
             self.stats.batches += 1
@@ -492,15 +507,17 @@ def encoded_batch_response(evaluator: EvaluationService, cache,
         if blob is not None:
             return blob
     results, meta = evaluator.evaluate_batch(list(queries))
-    if binary:
-        payload = results[0] if single \
-            else {"results": results, "batch": meta}
-        blob = wire.encode_frame(payload)
-    else:
-        payload = wire_result(results[0]) if single \
-            else {"results": [wire_result(r) for r in results],
-                  "batch": meta}
-        blob = json.dumps(payload, default=str).encode()
+    with obs_trace.span("wire_encode") as sp:
+        if binary:
+            payload = results[0] if single \
+                else {"results": results, "batch": meta}
+            blob = wire.encode_frame(payload)
+        else:
+            payload = wire_result(results[0]) if single \
+                else {"results": [wire_result(r) for r in results],
+                      "batch": meta}
+            blob = json.dumps(payload, default=str).encode()
+        sp["bytes"] = len(blob)
     if cell is not None and all(r.get("executable") == "hit"
                                 for r in results):
         cache.put(cell, blob, evaluator.cache_generation(),

@@ -445,8 +445,10 @@ def test_one_trace_spans_forward_and_peer_pull(tmp_path):
             time.sleep(0.05)
         assert len(owners) == 2, f"expected 2 replicas, got {len(owners)}"
         non_owner = next(s for s in servers if s not in owners)
-        # the forwarder hops to its first replica peer — evict exactly that
+        # ring order, not the loaded selector's latency ranking, so that the
+        # forwarder hops to its first replica peer — evict exactly that
         # node's copy so the forwarded derive must peer-pull
+        non_owner.router.policy = "static"
         by_url = {s.url: s for s in servers}
         primary = by_url[non_owner.cluster.replica_peers(key)[0]]
         assert primary in owners
