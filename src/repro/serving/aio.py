@@ -102,17 +102,23 @@ class _Conn:
             raise ValueError("request body must be a JSON object")
         return body
 
-    async def send_bytes(self, status: int, body: bytes,
+    async def send_bytes(self, status: int, body: bytes | memoryview,
                          content_type: str = "application/json",
                          close: bool = False) -> None:
         if close:
             self.keep_alive = False
         self.responded = True
+        if self.writer.is_closing():
+            # the peer left while the answer was being made; a closed
+            # transport cannot take buffers (what drain() would raise)
+            raise ConnectionResetError("connection lost before the response")
         # echo the active trace ID (set by _dispatch) back to the caller
         extra = obs_trace.wire_headers() or None
-        with obs_trace.span("wire_send", bytes=len(body)):
-            self.writer.write(
-                _head(status, content_type, len(body), close, extra) + body)
+        # two buffers, one sendmsg: the body is never copied into a
+        # concatenation with its head
+        with obs_trace.span("wire_send", bytes=len(body), buffers=2):
+            self.writer.writelines(
+                (_head(status, content_type, len(body), close, extra), body))
         with obs_trace.span("wire_drain"):
             await self.writer.drain()
 

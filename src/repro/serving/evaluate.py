@@ -483,11 +483,12 @@ def wire_result(res: dict) -> dict:
 
 def encoded_batch_response(evaluator: EvaluationService, cache,
                            queries: Sequence[dict], *, single: bool,
-                           binary: bool) -> bytes:
+                           binary: bool) -> bytes | memoryview:
     """Evaluate a (single|batch) request straight to encoded response
-    bytes, through an optional :class:`~repro.serving.wire.WireCache` —
-    the one evaluate hot path both frontends share, so the threaded and
-    asyncio servers can never disagree on bytes.
+    bytes (a read-only view of the frame, when binary), through an
+    optional :class:`~repro.serving.wire.WireCache` — the one evaluate hot
+    path both frontends share, so the threaded and asyncio servers can
+    never disagree on bytes.
 
     Cache policy mirrors the async frontend's derive blob cache: only
     responses whose every member rode an already-compiled executable
@@ -511,7 +512,7 @@ def encoded_batch_response(evaluator: EvaluationService, cache,
         if binary:
             payload = results[0] if single \
                 else {"results": results, "batch": meta}
-            blob = wire.encode_frame(payload)
+            blob = wire.encode_frame(payload, stats=sp)
         else:
             payload = wire_result(results[0]) if single \
                 else {"results": [wire_result(r) for r in results],

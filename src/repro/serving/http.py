@@ -373,7 +373,7 @@ def _make_handler(server: MappingHTTPServer):
             body = json.dumps(payload, default=str).encode()
             self._send_body(status, body, "application/json")
 
-        def _send_body(self, status: int, body: bytes,
+        def _send_body(self, status: int, body: bytes | memoryview,
                        content_type: str) -> None:
             self.send_response(status)
             self.send_header("Content-Type", content_type)

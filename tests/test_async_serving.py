@@ -3,12 +3,14 @@ wire surface as the threaded server (pooled keep-alive clients work against
 either unchanged), typed backend errors map to 503/504 and surface as typed
 client errors after retry exhaustion, slow readers stall only their own
 stream, and the frontend sheds with 503 under admission pressure."""
+import asyncio
 import json
 import socket
 import threading
 import time
 import urllib.request
 
+import numpy as np
 import pytest
 
 from repro.core.artifact import ArtifactCache
@@ -20,6 +22,8 @@ from repro.serving import (
     MappingService, RemoteBusyError, RemoteMappingService,
     RemoteTimeoutError,
 )
+from repro.serving import wire
+from repro.serving.aio import _Conn
 from repro.serving.async_engine import AsyncEngineBackend
 
 MODEL = "OSS:120b"
@@ -287,6 +291,86 @@ def test_full_batch_dispatches_without_waiting():
     assert bb.stats.batches == 1
     assert bb.stats.max_batch_seen == 4
     assert bb.stats.batched_requests == 4
+
+
+# ---------------------------------------------------------------------------
+# The send: head and body go to the transport as two buffers
+# ---------------------------------------------------------------------------
+
+
+class StubWriter:
+    """Records what ``_Conn.send_bytes`` hands the stream writer."""
+
+    def __init__(self, closing: bool = False):
+        self.closing = closing
+        self.calls: list = []
+
+    def is_closing(self) -> bool:
+        return self.closing
+
+    def write(self, data) -> None:
+        self.calls.append(("write", data))
+
+    def writelines(self, data) -> None:
+        self.calls.append(("writelines", tuple(data)))
+
+    async def drain(self) -> None:
+        self.calls.append(("drain",))
+
+
+def test_send_bytes_passes_head_and_body_unjoined():
+    body = wire.encode_frame(
+        {"coords": np.arange(64, dtype=np.int32).reshape(32, 2)})
+    writer = StubWriter()
+    asyncio.run(_Conn(None, writer).send_bytes(
+        200, body, content_type=wire.CONTENT_TYPE))
+    (kind, buffers), drained = writer.calls
+    assert kind == "writelines" and drained == ("drain",)
+    head, sent = buffers
+    assert sent is body  # the frame itself, never copied into a join
+    assert head.startswith(b"HTTP/1.1 200 OK\r\n")
+    assert head.endswith(b"\r\n\r\n")
+    assert f"Content-Length: {len(body)}\r\n".encode() in head
+    assert f"Content-Type: {wire.CONTENT_TYPE}\r\n".encode() in head
+    # a peer that left while the answer was made: the error drain() would
+    # raise, and nothing handed to the closed transport
+    gone = StubWriter(closing=True)
+    with pytest.raises(ConnectionResetError):
+        asyncio.run(_Conn(None, gone).send_bytes(200, b"{}"))
+    assert gone.calls == []
+
+
+def test_send_bytes_delivers_a_large_frame_through_a_socket():
+    """Over a real transport the two buffers arrive as one response, in
+    order, however many sends the body takes."""
+    body = wire.encode_frame({"coords": np.arange(
+        3 << 20, dtype=np.int32).reshape(-1, 3)})
+
+    async def main() -> bytes:
+        async def handle(reader, writer):
+            await _Conn(reader, writer).send_bytes(
+                200, body, content_type=wire.CONTENT_TYPE, close=True)
+            writer.close()
+
+        server = await asyncio.start_server(handle, "127.0.0.1", 0)
+        port = server.sockets[0].getsockname()[1]
+        try:
+            reader, writer = await asyncio.open_connection("127.0.0.1",
+                                                           port)
+            data = await asyncio.wait_for(reader.read(), 30)
+            writer.close()
+        finally:
+            server.close()
+            await server.wait_closed()
+        return data
+
+    data = asyncio.run(main())
+    head, sep, rest = data.partition(b"\r\n\r\n")
+    assert sep and b"Connection: close" in head
+    assert rest == bytes(body)
+    back = wire.decode_frame(rest)
+    np.testing.assert_array_equal(back["coords"].ravel(),
+                                  np.arange(3 << 20, dtype=np.int32))
 
 
 # ---------------------------------------------------------------------------

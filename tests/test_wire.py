@@ -9,6 +9,7 @@ import io
 import json
 import struct
 import time
+import tracemalloc
 
 import numpy as np
 import pytest
@@ -17,13 +18,14 @@ from repro.core import compile_cache as cc
 from repro.core.artifact import ArtifactCache
 from repro.core.backends import MockLLMBackend
 from repro.core.store import build_store
+from repro.obs import trace as obs_trace
 from repro.serving import (
     AsyncMappingHTTPServer, ClusterMembership, MappingHTTPServer,
     MappingService, RemoteMappingService, WireFormatError,
 )
 from repro.serving import wire
-from repro.serving.evaluate import EvaluationService, hydrate_result, \
-    wire_result
+from repro.serving.evaluate import EvaluationService, \
+    encoded_batch_response, hydrate_result, wire_result
 
 MODEL = "OSS:120b"
 FRONTENDS = [MappingHTTPServer, AsyncMappingHTTPServer]
@@ -94,7 +96,8 @@ def test_decoded_arrays_are_zero_copy_views():
     assert not arr.flags.owndata
 
 
-def _tamper(blob: bytes, what: str) -> bytes:
+def _tamper(blob: bytes | memoryview, what: str) -> bytes:
+    blob = bytes(blob)
     if what == "magic":
         return b"XXXX" + blob[4:]
     if what == "version":
@@ -168,6 +171,160 @@ def test_stream_framing_roundtrip_and_truncation():
         list(wire.iter_stream(io.BytesIO(stream[:2]).read))
 
 
+# ---------------------------------------------------------------------------
+# one-pass encoder: the same bytes as the two-copy encoder, one allocation
+# ---------------------------------------------------------------------------
+
+
+def reference_frame(payload) -> bytes:
+    """The frame as the two-copy encoder built it: a contiguous
+    little-endian copy of each array, ``tobytes``, and one join."""
+    segments: list = []
+    stripped = wire._strip_arrays(payload, segments)
+    segments = [a.astype(a.dtype.newbyteorder("<"))
+                if a.dtype.byteorder == ">" else a for a in segments]
+    head = json.dumps({"payload": stripped,
+                       "segments": [{"dtype": a.dtype.name,
+                                     "shape": list(a.shape)}
+                                    for a in segments]},
+                      default=str).encode()
+    parts = [wire.MAGIC, struct.pack("<I", wire.VERSION),
+             struct.pack("<I", len(head)), head]
+    for arr in segments:
+        raw = np.ascontiguousarray(arr).tobytes()
+        parts += [struct.pack("<I", len(raw)), raw]
+    return b"".join(parts)
+
+
+def _block(n: int, seed: int = 0) -> np.ndarray:
+    """An evaluate launch's output: (8, padded) int32, C-ordered."""
+    padded = 1 << max(n - 1, 1).bit_length()
+    return np.random.default_rng(seed).integers(
+        -(1 << 30), 1 << 30, (8, padded), dtype=np.int32)
+
+
+def _payload(case: str):
+    block = _block(3000)
+    if case.startswith("transposed-dim"):
+        dim = int(case[len("transposed-dim"):])
+        return {"domain": "tri2d", "n_points": 3000,
+                "coords": block[:dim, :3000].T}
+    if case == "bool-mask":
+        return {"mask": block[0, :3000] > 0,
+                "grid": (block[:3, :500] > 0).T}
+    if case == "big-endian":
+        return {"be": block[:3, :3000].T.astype(">i4"),
+                "be_flat": block[0, :777].astype(">i8"),
+                "be_f64": np.linspace(-1, 1, 33).astype(">f8")}
+    if case == "zero-length":
+        return {"coords": block[:2, :0].T,
+                "empty": np.array([], dtype=np.int32),
+                "tail": np.arange(5, dtype=np.int32)}
+    if case == "nested-batch":
+        results = [{"index": i, "domain": d, "n_points": n,
+                    "coords": _block(n, i)[:dim, :n].T}
+                   for i, (d, dim, n) in enumerate(
+                       [("tri2d", 2, 1000), ("msimplex3", 3, 700),
+                        ("msimplex5", 5, 513), ("msimplex4", 4, 64)])]
+        results.append({"index": 4, "tier": "membership",
+                        "mask": block[0, :144] > 0})
+        return {"results": results,
+                "batch": {"queries": 5, "groups": np.int64(4),
+                          "strided": block[::2, ::3][:, :40],
+                          "scalar": np.array(7, dtype=np.int16)}}
+    raise AssertionError(case)
+
+
+def _arrays(obj) -> list:
+    found: list = []
+    wire._strip_arrays(obj, found)
+    return found
+
+
+@pytest.mark.parametrize("case", [
+    "transposed-dim2", "transposed-dim3", "transposed-dim4",
+    "transposed-dim5", "transposed-dim7", "bool-mask", "big-endian", "zero-length",
+    "nested-batch"])
+def test_one_pass_frame_is_byte_identical_to_two_copy_frame(case):
+    payload = _payload(case)
+    blob = wire.encode_frame(payload)
+    assert isinstance(blob, memoryview) and blob.readonly
+    with pytest.raises(TypeError):
+        blob[0] = 0
+    assert bytes(blob) == reference_frame(payload)
+    assert struct.unpack_from("<I", blob, 4)[0] == wire.VERSION == 1
+    back = wire.decode_frame(blob)
+    sent, got = _arrays(payload), _arrays(back)
+    assert len(sent) == len(got)
+    for a, b in zip(sent, got):
+        np.testing.assert_array_equal(b, a)
+        assert b.dtype == a.dtype.newbyteorder("<")
+        assert b.shape == a.shape
+
+
+def test_encoding_allocates_one_frame():
+    """The frame buffer is the only large allocation: the peak of traced
+    memory while encoding a batch of several MB stays within 1.1x the
+    frame's length (the two-copy encoder peaks at 2x: its parts and
+    their join)."""
+    results = [{"index": i, "coords": _block(n, i)[:dim, :n].T}
+               for i, (dim, n) in enumerate(
+                   [(2, 300_000), (3, 250_000), (5, 200_000), (4, 100_000)])]
+    payload = {"results": results, "batch": {"queries": len(results)}}
+    frame_len = len(reference_frame(payload))
+    assert frame_len > 10 << 20
+
+    def peak(encode) -> int:
+        tracemalloc.start()
+        try:
+            blob = encode(payload)
+            _, top = tracemalloc.get_traced_memory()
+        finally:
+            tracemalloc.stop()
+        assert len(blob) == frame_len
+        return top
+
+    assert peak(wire.encode_frame) <= 1.1 * frame_len
+    assert peak(reference_frame) > 1.9 * frame_len
+    stats: dict = {}
+    blob = wire.encode_frame(payload, stats=stats)
+    head_len = struct.unpack_from("<I", blob, 8)[0]
+    assert stats["frame"] == len(blob) == frame_len
+    assert stats["copied"] == \
+        stats["frame"] - 12 - head_len - 4 * len(results)
+    assert stats["columnwise"] == sum(
+        1 for r in results
+        if r["coords"].shape[1] <= wire._COLUMNWISE_MAX_DIM)
+
+
+def test_wire_encode_span_counts_each_array_byte_once():
+    """On the evaluate path, the ``wire_encode`` span carries the frame's
+    length, the array bytes written into it (everything but the preamble,
+    header and length prefixes) and the segments copied column by
+    column."""
+    ev = EvaluationService(compile_cache=cc.CompileCache(max_entries=8))
+    queries = [{"domain": "tri2d", "n_points": 200, "block_n": 128},
+               {"domain": "msimplex3", "n_points": 150, "block_n": 128},
+               {"domain": "tri2d", "tier": "membership",
+                "extent": [12, 12]}]
+    t0 = time.monotonic()
+    token = obs_trace.activate(obs_trace.TraceBuffer(), "e5" * 16)
+    try:
+        blob = encoded_batch_response(ev, None, queries, single=False,
+                                      binary=True)
+    finally:
+        obs_trace.deactivate(token)
+    (span,) = obs_trace.spans_between(t0, time.monotonic(), "wire_encode")
+    attrs = span[4]
+    head_len = struct.unpack_from("<I", blob, 8)[0]
+    segments = json.loads(bytes(blob[12:12 + head_len]))["segments"]
+    assert attrs["frame"] == attrs["bytes"] == len(blob)
+    assert attrs["copied"] == \
+        attrs["frame"] - 12 - head_len - 4 * len(segments)
+    assert attrs["columnwise"] == 2  # the two coords blocks; not the mask
+    assert [s["dtype"] for s in segments] == ["int32", "int32", "bool"]
+
+
 def test_wire_cache_generations_and_artifact_invalidation():
     cache = wire.WireCache(entries=2)
     cell = ("bin", "single", ("k",))
@@ -186,6 +343,28 @@ def test_wire_cache_generations_and_artifact_invalidation():
     assert cache.get(("a",)) is None and cache.get(("c",)) == b"3"
     stats = cache.stats_dict()
     assert stats["capacity"] == 2 and stats["hits"] >= 1
+
+
+def test_wire_cache_bounds_the_bytes_it_holds(monkeypatch):
+    """Past ``MAX_CACHED_BYTES`` the oldest blobs go, whatever the entry
+    bound; a blob larger than the whole budget is not cached at all."""
+    monkeypatch.setattr(wire, "MAX_CACHED_BYTES", 100)
+    cache = wire.WireCache(entries=16)
+    for i in range(4):
+        cache.put((i,), wire.encode_frame({"i": i}))
+    frame = len(cache.get((3,)))
+    assert 25 < frame <= 50  # two fit in 100 bytes, three do not
+    assert cache.get((0,)) is None and cache.get((1,)) is None
+    assert cache.get((2,)) is not None
+    assert cache.stats_dict()["bytes"] == 2 * frame
+    cache.put(("big",), b"x" * 101)
+    assert cache.get(("big",)) is None
+    assert cache.stats_dict()["entries"] == 2
+    cache.put((2,), b"y" * 10)  # replacing an entry re-counts its bytes
+    assert cache.stats_dict()["bytes"] == frame + 10
+    cache.invalidate_artifact("nothing")
+    cache.clear()
+    assert cache.stats_dict()["bytes"] == 0
 
 
 # ---------------------------------------------------------------------------
