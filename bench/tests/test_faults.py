@@ -6,7 +6,8 @@ with a fault planted where the answer is produced:
 
 * evaluate — one coordinate of every map launch altered in the kernel's
   output;
-* derive   — every token the engine chooses altered (the next token id).
+* derive   — every token the engine chooses altered (the next token id);
+  and a decode step that returns its state, the KV cache, unchanged.
 
 Slow (about a minute a case): run by hand, ``JAX_PLATFORMS=cpu python -m
 pytest -q bench/tests/test_faults.py``."""
@@ -62,6 +63,18 @@ def plant_token_fault(monkeypatch):
     monkeypatch.setattr(engine, "greedy", broken)
 
 
+def plant_state_fault(monkeypatch):
+    from repro.models import transformer as T
+
+    orig = T.decode_step
+
+    def broken(params, cfg, token, cache, extra=None):
+        logits, _ = orig(params, cfg, token, cache, extra)
+        return logits, cache
+
+    monkeypatch.setattr(T, "decode_step", broken)
+
+
 @pytest.mark.parametrize("mix_name", ["eval_batch8", "eval_single_zipf"])
 @pytest.mark.parametrize("fault", [False, True])
 def test_evaluate_answer_fault(monkeypatch, mix_name, fault):
@@ -81,3 +94,11 @@ def test_derive_token_fault(monkeypatch, fault):
     out = run.run_cell(cell, cfg, mix, 2**31 + 13, 6.0, False, False, DEVICE)
     assert out["attempted"] > 0
     assert out["correct"] is (not fault), out["checks"]
+
+
+def test_derive_state_fault(monkeypatch):
+    plant_state_fault(monkeypatch)
+    cell, cfg, mix = small_derive()
+    out = run.run_cell(cell, cfg, mix, 2**31 + 13, 6.0, False, False, DEVICE)
+    assert out["attempted"] > 0
+    assert out["correct"] is False, out["checks"]

@@ -1,14 +1,18 @@
 """Closed-loop ``POST /v1/derive`` traffic on the engine backend.
 
-One client process runs ``clients`` threads; each derives fresh cells back to
-back: the domain drawn uniformly from ``domains``, the stage taken without
-replacement from a permutation of ``stages`` drawn from ``--seed``.  The
-artifact store is new and empty in every run, so every derive runs the
-engine: prefill, then decode through the cohort's KV cache, then synthesis
-and validation.
+One client process runs ``clients`` threads; thread i starts at
+``t_load + i * start_spacing_s``, so that no two first requests fall into
+one admission of the batcher, and then derives fresh cells back to back.
+Domains are dealt from a deck, a permutation of ``domains`` drawn from
+``--seed``: the k-th derive of thread i takes entry (i + clients * k) mod
+len(domains), so that every seed asks for each domain as often, in another
+order.  Stages are taken without replacement from a permutation of
+``stages`` drawn from the seed.  The artifact store is new and empty in
+every run, so every derive runs the engine: prefill, then decode through
+the cohort's KV cache, then synthesis and validation.
 
-Set-up warms prefill and decode for every cohort size up to the decode slots
-by calling the server's own ``EngineStepper``.
+Set-up warms prefill and decode for every cohort size up to the decode
+slots by calling the server's own ``EngineStepper``.
 
 After the window the check takes a sample of the derives completed in it,
 drawn from the seed, and compares every token the engine chose with the
@@ -48,20 +52,21 @@ def counters(ctx) -> dict:
 
 # -- client side -------------------------------------------------------------
 
-def _thread(spec, thread, stages, t_load, t_w1, out):
+def _thread(spec, thread, deck, stages, t_load, t_w1, out):
     from repro.serving import RemoteMappingService
 
     mix, label = spec["mix"], spec["config"]["model_label"]
-    rng = np.random.default_rng([spec["seed"], thread])
+    n = mix["clients"]
     client = RemoteMappingService(spec["url"], timeout=600, retries=0)
     records = []
-    while time.monotonic() < t_load:
+    t_start = t_load + thread * mix["start_spacing_s"]
+    while time.monotonic() < t_start:
         time.sleep(0.001)
     try:
-        for stage in stages:
+        for k, stage in enumerate(stages):
             if time.monotonic() >= t_w1:
                 break
-            domain = mix["domains"][rng.integers(len(mix["domains"]))]
+            domain = mix["domains"][deck[(thread + n * k) % len(deck)]]
             t0 = time.monotonic()
             rec = dict(domain=domain, stage=int(stage), kind="derive")
             try:
@@ -86,11 +91,12 @@ def client_run(spec: dict, shared: dict, t_load: float, t_w0: float,
     mix = spec["mix"]
     n = mix["clients"]
     lo, hi = mix["stages"]
-    perm = np.random.default_rng(spec["seed"]).permutation(
-        np.arange(lo, hi + 1))
+    rng = np.random.default_rng(spec["seed"])
+    perm = rng.permutation(np.arange(lo, hi + 1))
+    deck = rng.permutation(len(mix["domains"]))
     out = {"records": [None] * n, "errors": []}
     threads = [threading.Thread(target=_thread, args=(
-        spec, i, perm[i::n], t_load, t_w1, out))
+        spec, i, deck, perm[i::n], t_load, t_w1, out))
         for i in spec.get("threads", range(n))]
     for t in threads:
         t.start()
