@@ -10,6 +10,18 @@ import dataclasses
 
 
 @dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN RoPE scaling (arXiv:2309.00071), as DeepSeek-V3 publishes it."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     arch_id: str
     family: str                     # dense | moe | ssm | hybrid | audio | vlm
@@ -25,6 +37,8 @@ class ModelConfig:
     attention_type: str = "gqa"     # gqa | mla | none
     tie_embeddings: bool = False
     norm_eps: float = 1e-6
+    rope_scaling: Yarn | None = None
+    first_dense_layers: int = 0     # leading layers with a d_ff MLP (moe)
     # --- MLA (deepseek-v2) ---
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -42,6 +56,11 @@ class ModelConfig:
     moe_renormalize: bool = True
     moe_groups: int = 1             # >1: group-local dispatch (G = data axis)
     moe_impl: str = "global"        # global | grouped | a2a (shard_map EP)
+    router: str = "softmax"         # softmax | sigmoid_group (noaux_tc)
+    n_groups: int = 1               # sigmoid_group: expert groups ...
+    topk_groups: int = 1            # ... of which the best are kept
+    routed_scaling: float = 1.0     # routed experts' weights x this
+    experts_held: int = 0           # experts 0..held-1 on this chip; 0 = all
     # --- RWKV6 ---
     rwkv_heads: int = 0
     rwkv_decay_lora: int = 64

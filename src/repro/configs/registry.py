@@ -16,16 +16,26 @@ _MODULES = {
     "granite-8b": "repro.configs.granite_8b",
     "zamba2-1.2b": "repro.configs.zamba2_1_2b",
     "whisper-medium": "repro.configs.whisper_medium",
+    "deepseek-v3": "repro.configs.deepseek_v3",
+    "deepseek-v3-ep32": "repro.configs.deepseek_v3",
 }
 
 ARCH_IDS = tuple(_MODULES)
 
 
-def get_config(arch_id: str) -> ModelConfig:
+def _module(arch_id: str):
     if arch_id not in _MODULES:
         raise KeyError(f"unknown arch {arch_id!r}; have {sorted(_MODULES)}")
-    return importlib.import_module(_MODULES[arch_id]).CONFIG
+    return importlib.import_module(_MODULES[arch_id])
+
+
+def get_config(arch_id: str) -> ModelConfig:
+    """The whole model, or one chip's share of it (a module's ``SHARES``)."""
+    mod = _module(arch_id)
+    return getattr(mod, "SHARES", {}).get(arch_id, mod.CONFIG)
 
 
 def get_smoke_config(arch_id: str) -> ModelConfig:
-    return importlib.import_module(_MODULES[arch_id]).smoke_config()
+    mod = _module(arch_id)
+    share = getattr(mod, "SHARES", {}).get(arch_id)
+    return mod.smoke_config() if share is None else mod.smoke_config(share)

@@ -62,7 +62,12 @@ def cache_specs(cfg: ModelConfig):
         inner = (_mla_cache_specs(cfg.kv_cache_quant)
                  if cfg.attention_type == "mla"
                  else _gqa_cache_specs(cfg.kv_cache_quant))
-        return {"layers": inner}
+        specs = {"layers": inner}
+        if cfg.first_dense_layers:
+            specs["dense_layers"] = inner
+        if cfg.family == "moe":
+            specs["moe_counts"] = (None,)
+        return specs
     if cfg.family == "vlm":
         base = _gqa_cache_specs(cfg.kv_cache_quant)
         return {"layers": {"self": {

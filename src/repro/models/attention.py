@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from repro.distribution.sharding import logical_constraint as lc
 from repro.models.common import (
     EMBED, HEAD_DIM, HEADS, KV_HEADS, dense_init, rms_norm, rope,
+    yarn_softmax_scale,
 )
 
 NEG_INF = -1e30
@@ -26,19 +27,19 @@ _Q_CHUNK = 256
 
 
 def _sdpa(q, k, v, n_kv_heads, q_pos=None, chunk: int = _Q_CHUNK,
-          logit_dim: int | None = None):
+          scale: float | None = None):
     """Grouped SDPA, fp32 softmax, q-chunked.
 
     q: (B, S, H, D); k, v: (B, T, Hk, D).
     q_pos: (B, S) absolute positions — causal mask "kv_index <= q_pos";
            None => no mask (cross / bidirectional attention).
-    logit_dim: scale denominator (defaults to D — MLA passes nope+rope).
+    scale: softmax scale (defaults to D^-0.5 — MLA passes its own).
     """
     b, s, h, d = q.shape
     t = k.shape[1]
     dv = v.shape[-1]
     g = h // n_kv_heads
-    scale = (logit_dim or d) ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
     kv_idx = jnp.arange(t)
@@ -202,8 +203,8 @@ def gqa_apply(p, cfg, x, *, positions=None, cache=None, cross_kv=None):
         k = jnp.einsum("btd,dhe->bthe", cross_kv, p["wk"])
         v = jnp.einsum("btd,dhe->bthe", cross_kv, p["wv"])
     if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"])
-        k = rms_norm(k, p["k_norm"])
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
     else:
@@ -345,16 +346,18 @@ def mla_apply(p, cfg, x, *, positions=None, cache=None, cross_kv=None):
         positions = jnp.broadcast_to(positions, (b, s))
 
     q = jnp.einsum("bsl,lhe->bshe",
-                   rms_norm(jnp.einsum("bsd,dl->bsl", x, p["wdq"]), p["q_norm"]),
+                   rms_norm(jnp.einsum("bsd,dl->bsl", x, p["wdq"]),
+                            p["q_norm"], cfg.norm_eps),
                    p["wuq"])
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = rope(q_rope, positions, cfg.rope_theta)
+    q_rope = rope(q_rope, positions, cfg.rope_theta, cfg.rope_scaling)
     q_full = jnp.concatenate([q_nope, q_rope], axis=-1)   # (B,S,H,dn+dr)
     q_full = lc(q_full, "batch", None, "heads", None)
 
     ckv = jnp.einsum("bsd,dl->bsl", x, p["wdkv"])          # compressed kv
     krope = rope(jnp.einsum("bsd,dr->bsr", x, p["wkr"])[:, :, None, :],
-                 positions, cfg.rope_theta)[:, :, 0, :]    # shared rope key
+                 positions, cfg.rope_theta,
+                 cfg.rope_scaling)[:, :, 0, :]             # shared rope key
 
     new_cache = None
     if cache is not None:
@@ -367,8 +370,10 @@ def mla_apply(p, cfg, x, *, positions=None, cache=None, cross_kv=None):
         ckv = _cache_get(new_cache, "ckv", x.dtype)
         krope = _cache_get(new_cache, "krope", x.dtype)
     ckv = lc(ckv, "batch", "kv_seq", "kv_lora")
-    ckv_n = rms_norm(ckv, p["kv_norm"])
+    ckv_n = rms_norm(ckv, p["kv_norm"], cfg.norm_eps)
     t = ckv_n.shape[1]
+    # YaRN's mscale squared rides on the softmax scale, in both paths
+    scale = (dn + dr) ** -0.5 * yarn_softmax_scale(cfg.rope_scaling)
 
     absorb = (cfg.mla_absorb != "never" and cache is not None and s <= 32)
     if absorb:
@@ -376,7 +381,6 @@ def mla_apply(p, cfg, x, *, positions=None, cache=None, cross_kv=None):
         # attention in the compressed kv_lora space — the per-step
         # up-projection of the whole cache (2·T·kl·H·(dn+dv) flops) vanishes.
         #   q·k = (W_uk q_nope)·c_kv ;  probs·v = (probs·c_kv)·W_uv
-        scale = (dn + dr) ** -0.5
         q_abs = jnp.einsum("bshe,lhe->bshl", q_nope, p["wuk"])
         logits = (
             jnp.einsum("bshl,btl->bhst", q_abs.astype(jnp.float32),
@@ -403,7 +407,7 @@ def mla_apply(p, cfg, x, *, positions=None, cache=None, cross_kv=None):
     k_full = lc(k_full, "batch", "kv_seq", "heads", None)
     v = lc(v, "batch", "kv_seq", "heads", None)
 
-    out = _sdpa(q_full, k_full, v, h, positions, logit_dim=dn + dr)
+    out = _sdpa(q_full, k_full, v, h, positions, scale=scale)
     out = out.reshape(b, s, h * dv)
     y = jnp.einsum("bsf,fd->bsd", out, p["wo"])
     return y, new_cache

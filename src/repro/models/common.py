@@ -73,14 +73,52 @@ def mlp_specs():
     return {"gate": (EMBED, FFN), "up": (EMBED, FFN), "down": (FFN, EMBED)}
 
 
-def rope(x, positions, theta: float = 10000.0):
-    """Rotary embedding over the last dim of (..., seq, n_heads, head_dim)."""
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * np.log(factor) + 1.0
+
+
+def yarn_softmax_scale(scaling) -> float:
+    """The factor YaRN puts on the attention softmax scale (DeepSeek's
+    ``mscale_all_dim``, applied to q and k alike: squared)."""
+    if scaling is None:
+        return 1.0
+    return _yarn_mscale(scaling.factor, scaling.mscale_all_dim) ** 2
+
+
+def _yarn_ramp(half: int, theta: float, scaling) -> np.ndarray:
+    """Per frequency, the share taken from the interpolated (÷ factor)
+    frequency: 0 below the correction dim of ``beta_fast`` rotations over
+    the original context, 1 above that of ``beta_slow``, linear between."""
+    dim = 2 * half
+
+    def corr(rot):
+        return dim * np.log(scaling.original_max_position
+                            / (rot * 2 * np.pi)) / (2 * np.log(theta))
+
+    low = max(np.floor(corr(scaling.beta_fast)), 0)
+    high = min(np.ceil(corr(scaling.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    return np.clip((np.arange(half) - low) / (high - low), 0, 1
+                   ).astype(np.float32)
+
+
+def rope(x, positions, theta: float = 10000.0, scaling=None):
+    """Rotary embedding over the last dim of (..., seq, n_heads, head_dim);
+    ``scaling`` (a ``configs.base.Yarn``) blends in YaRN's frequencies."""
     head_dim = x.shape[-1]
     half = head_dim // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if scaling is not None:
+        ramp = _yarn_ramp(half, theta, scaling)
+        freqs = freqs / scaling.factor * ramp + freqs * (1 - ramp)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., seq, half)
     cos = jnp.cos(angles)[..., :, None, :]  # broadcast over heads
     sin = jnp.sin(angles)[..., :, None, :]
+    if scaling is not None:
+        amp = (_yarn_mscale(scaling.factor, scaling.mscale)
+               / _yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        cos, sin = cos * amp, sin * amp
     x1, x2 = x[..., :half], x[..., half:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     return jnp.concatenate(

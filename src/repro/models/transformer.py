@@ -1,8 +1,13 @@
-"""Composable model assembly for all 10 assigned architectures.
+"""Composable model assembly for every architecture in ``configs``.
 
 Families:
   dense / moe — decoder-only transformer (GQA or MLA attention, SwiGLU MLP or
-                sort-dispatch MoE), scan-over-layers.
+                sort-dispatch MoE), scan-over-layers.  A MoE model with
+                ``first_dense_layers`` scans a dense stack
+                (``dense_layers``) and then the MoE stack (``layers``),
+                each with its own stacked params and cache; serving caches
+                of MoE models carry the expert layer's counters
+                (``moe_counts``, ``moe.COUNTERS``) summed on the device.
   vlm         — decoder with one gated cross-attention (image) layer per
                 ``cross_attn_every``-layer group; patch embeddings are a stub
                 input (precomputed, already projected to d_model).
@@ -105,7 +110,7 @@ def _stack_cache(one, n: int):
 
 
 def _logits(params, cfg, h):
-    h = rms_norm(h, params["final_norm"])
+    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.einsum("bsd,dv->bsv", h, w,
                         preferred_element_type=jnp.float32)
@@ -134,7 +139,8 @@ def _bidir_attn(lp_attn, cfg, x):
 # ===========================================================================
 
 
-def _layer_init(key, cfg, dtype, cross: bool = False):
+def _layer_init(key, cfg, dtype, cross: bool = False, moe: bool = True):
+    """moe=False: a MoE model's leading dense layer (SwiGLU of d_ff)."""
     k1, k2 = jax.random.split(key)
     p: dict[str, Any] = {
         "ln1": jnp.ones((cfg.d_model,), dtype),
@@ -142,7 +148,7 @@ def _layer_init(key, cfg, dtype, cross: bool = False):
         "attn": attn.gqa_init(k1, cfg, dtype) if cross
         else _attn_init(k1, cfg, dtype),
     }
-    if cfg.family == "moe" and not cross:
+    if cfg.family == "moe" and moe and not cross:
         p["moe"] = moe_mod.moe_init(k2, cfg, dtype)
     else:
         p["mlp"] = mlp_init(k2, cfg.d_model, cfg.d_ff, dtype)
@@ -151,12 +157,12 @@ def _layer_init(key, cfg, dtype, cross: bool = False):
     return p
 
 
-def _layer_specs(cfg, cross: bool = False):
+def _layer_specs(cfg, cross: bool = False, moe: bool = True):
     s: dict[str, Any] = {
         "ln1": (EMBED,), "ln2": (EMBED,),
         "attn": attn.gqa_specs(cfg) if cross else _attn_specs(cfg),
     }
-    if cfg.family == "moe" and not cross:
+    if cfg.family == "moe" and moe and not cross:
         s["moe"] = moe_mod.moe_specs(cfg)
     else:
         s["mlp"] = mlp_specs()
@@ -167,55 +173,86 @@ def _layer_specs(cfg, cross: bool = False):
 
 def _layer_apply(p, cfg, x, *, positions=None, cache=None, cross_kv=None,
                  with_aux: bool = False):
+    """(x, new_cache, aux, counts): aux (the MoE load-balancing loss) only
+    with ``with_aux``, counts (``moe.COUNTERS``) only from a MoE layer that
+    serves against a cache; None otherwise."""
     cross = cross_kv is not None
     apply = attn.gqa_apply if cross else _attn_apply
     h, new_cache = apply(
-        p["attn"], cfg, rms_norm(x, p["ln1"]), positions=positions,
-        cache=cache, cross_kv=cross_kv,
+        p["attn"], cfg, rms_norm(x, p["ln1"], cfg.norm_eps),
+        positions=positions, cache=cache, cross_kv=cross_kv,
     )
     if cross:
         h = h * jnp.tanh(p["xattn_gate"]).astype(h.dtype)
     x = x + h
-    inner = rms_norm(x, p["ln2"])
-    aux = jnp.zeros((), jnp.float32)
-    if "moe" in p:
+    inner = rms_norm(x, p["ln2"], cfg.norm_eps)
+    aux = jnp.zeros((), jnp.float32) if with_aux else None
+    counts = None
+    if "moe" in p and cache is not None:
+        out, counts = moe_mod.moe_serve(p["moe"], cfg, inner)
+        x = x + out
+    elif "moe" in p:
         out = moe_mod.moe_apply(p["moe"], cfg, inner, with_aux=with_aux)
         if with_aux:
             out, aux = out
         x = x + out
     else:
         x = x + swiglu(inner, p["mlp"]["gate"], p["mlp"]["up"], p["mlp"]["down"])
-    return (x, new_cache, aux) if with_aux else (x, new_cache)
+    return x, new_cache, aux, counts
 
 
-def _decoder_stack(params, cfg, x, *, positions=None, caches=None,
-                   cross_states=None, with_aux: bool = False):
-    """Scan over layers; caches is a stacked pytree or None (both work).
-
-    with_aux additionally returns the summed MoE load-balancing loss.
-    """
+def _scan_stack(layers, cfg, x, *, positions, caches, with_aux):
+    """Scan one homogeneous stack: (x, new_caches, aux summed or None,
+    counts summed over its layers or None)."""
     body = _remat(
         lambda xx, lp, c: _layer_apply(lp, cfg, xx, positions=positions,
                                        cache=c, with_aux=with_aux),
         _pol(cfg),
     )
 
+    def f(xx, lp_c):
+        lp, c = lp_c
+        xx, nc, aux, counts = body(xx, lp, c)
+        return xx, (nc, aux, counts)
+
+    x, (ncs, aux, counts) = jax.lax.scan(f, x, (layers, caches))
+    return (x, ncs, None if aux is None else jnp.sum(aux),
+            None if counts is None else jnp.sum(counts, axis=0))
+
+
+def _decoder_stack(params, cfg, x, *, positions=None, cache=None,
+                   cross_states=None, with_aux: bool = False):
+    """Scan over layers: (x, new_cache, aux).  ``cache`` is the whole
+    decoder cache or None (both work; new_cache is None without one);
+    with_aux additionally sums the MoE load-balancing loss (else None).
+    """
     if cfg.family != "vlm":
-        def f(xx, lp_c):
-            lp, c = lp_c
-            out = body(xx, lp, c)
-            if with_aux:
-                return out[0], (out[1], out[2])
-            return out
-        x, ys = jax.lax.scan(f, x, (params["layers"], caches))
-        if with_aux:
-            return x, ys[0], jnp.sum(ys[1])
-        return x, ys
+        stacks = (["dense_layers"] if cfg.first_dense_layers else []) \
+            + ["layers"]
+        new_cache = None if cache is None else dict(cache)
+        aux_total = None
+        for name in stacks:
+            x, ncs, aux, counts = _scan_stack(
+                params[name], cfg, x, positions=positions,
+                caches=None if cache is None else cache[name],
+                with_aux=with_aux)
+            if new_cache is not None:
+                new_cache[name] = ncs
+            if counts is not None:
+                new_cache["moe_counts"] = cache["moe_counts"] + counts
+            if aux is not None:
+                aux_total = aux if aux_total is None else aux_total + aux
+        return x, new_cache, aux_total
 
     # vlm: groups of (cross_attn_every - 1) self layers + 1 cross layer
+    body = _remat(
+        lambda xx, lp, c: _layer_apply(lp, cfg, xx, positions=positions,
+                                       cache=c)[:2],
+        _pol(cfg),
+    )
     cross_body = _remat(
         lambda xx, lp, c: _layer_apply(lp, cfg, xx, positions=positions,
-                                       cache=c, cross_kv=cross_states),
+                                       cache=c, cross_kv=cross_states)[:2],
         _pol(cfg),
     )
 
@@ -231,7 +268,10 @@ def _decoder_stack(params, cfg, x, *, positions=None, caches=None,
         xx, _ = cross_body(xx, gp["cross"], None)
         return xx, (None if gc is None else {"self": new_self})
 
-    return jax.lax.scan(group_fn, x, (params["groups"], caches))
+    x, new_groups = jax.lax.scan(
+        group_fn, x,
+        (params["groups"], None if cache is None else cache["layers"]))
+    return x, (None if cache is None else {"layers": new_groups}), None
 
 
 # ===========================================================================
@@ -260,7 +300,7 @@ def _rwkv_layer_specs(cfg):
 def _rwkv_layer_apply(p, cfg, x, state):
     """state: dict(tmix_x, cmix_x, wkv). Chunked when seq allows, else scan."""
     use_scan = (x.shape[1] % 64 != 0)
-    n1 = rms_norm(x, p["ln1"])
+    n1 = rms_norm(x, p["ln1"], cfg.norm_eps)
     if use_scan:
         o, last_x, wkv = rwkv.rwkv_mix_scan(p["tmix"], cfg, n1,
                                             state["tmix_x"], state["wkv"])
@@ -268,7 +308,7 @@ def _rwkv_layer_apply(p, cfg, x, state):
         o, last_x, wkv = rwkv.rwkv_mix_chunked(p["tmix"], cfg, n1,
                                                state["tmix_x"], state["wkv"])
     x = x + o
-    n2 = rms_norm(x, p["ln2"])
+    n2 = rms_norm(x, p["ln2"], cfg.norm_eps)
     o2, last_c = rwkv.rwkv_cmix_apply(p["cmix"], cfg, n2, state["cmix_x"])
     x = x + o2
     return x, {"tmix_x": last_x, "cmix_x": last_c, "wkv": wkv}
@@ -321,11 +361,11 @@ def _zamba_shared_specs(cfg):
 
 
 def _zamba_shared_apply(p, cfg, x, positions=None, cache=None):
-    h, nc = attn.gqa_apply(p["attn"], cfg, rms_norm(x, p["ln1"]),
+    h, nc = attn.gqa_apply(p["attn"], cfg, rms_norm(x, p["ln1"], cfg.norm_eps),
                            positions=positions, cache=cache)
     x = x + h
-    x = x + swiglu(rms_norm(x, p["ln2"]), p["mlp"]["gate"], p["mlp"]["up"],
-                   p["mlp"]["down"])
+    x = x + swiglu(rms_norm(x, p["ln2"], cfg.norm_eps), p["mlp"]["gate"],
+                   p["mlp"]["up"], p["mlp"]["down"])
     return x, nc
 
 
@@ -343,7 +383,8 @@ def _hybrid_stack(params, cfg, x, *, positions=None, cache=None):
 
     mamba_body = _remat(
         lambda xx, lp, st, tl: m2.mamba2_apply(
-            lp["mamba"], cfg, rms_norm(xx, lp["ln"]), state=st, conv_tail=tl),
+            lp["mamba"], cfg, rms_norm(xx, lp["ln"], cfg.norm_eps), state=st,
+            conv_tail=tl),
         _pol(cfg))
     shared_plain = _remat(
         lambda xx: _zamba_shared_apply(shared, cfg, xx, positions=positions)[0],
@@ -538,8 +579,14 @@ def init_params(key, cfg):
                                          cross=True),
                 }, k3, n_groups)
         else:
-            params["layers"] = stack_layers(
-                lambda k: _layer_init(k, cfg, dtype), k3, cfg.n_layers)
+            # layer i draws from key i of the split whichever stack holds it
+            keys = jax.random.split(k3, cfg.n_layers)
+            nd = cfg.first_dense_layers
+            if nd:
+                params["dense_layers"] = jax.vmap(
+                    lambda k: _layer_init(k, cfg, dtype, moe=False))(keys[:nd])
+            params["layers"] = jax.vmap(
+                lambda k: _layer_init(k, cfg, dtype))(keys[nd:])
         return params
     if cfg.family == "ssm":
         k1, k2, k3 = jax.random.split(key, 3)
@@ -596,6 +643,9 @@ def param_specs(cfg):
                 "cross": _layer_specs(cfg, cross=True),
             })
         else:
+            if cfg.first_dense_layers:
+                specs["dense_layers"] = prepend_layers_axis(
+                    _layer_specs(cfg, moe=False))
             specs["layers"] = prepend_layers_axis(_layer_specs(cfg))
         return specs
     if cfg.family == "ssm":
@@ -643,11 +693,10 @@ def forward(params, cfg, tokens, extra=None, positions=None,
     x = _embed(params, cfg, tokens)
     if cfg.family in ("dense", "moe", "vlm"):
         collect = with_aux and cfg.family == "moe"
-        res = _decoder_stack(params, cfg, x, positions=positions,
-                             cross_states=extra, with_aux=collect)
-        x = res[0]
+        x, _, moe_aux = _decoder_stack(params, cfg, x, positions=positions,
+                                       cross_states=extra, with_aux=collect)
         if collect:
-            aux = res[2]
+            aux = moe_aux
     elif cfg.family == "ssm":
         x, _ = _rwkv_stack(params, cfg, x)
     elif cfg.family == "hybrid":
@@ -661,8 +710,15 @@ def forward(params, cfg, tokens, extra=None, positions=None,
 def init_cache(cfg, batch: int, max_seq: int, extra_len: int = 0):
     dtype = _dtype(cfg)
     if cfg.family in ("dense", "moe"):
-        return {"layers": _stack_cache(
-            _attn_cache_init(cfg, batch, max_seq, dtype), cfg.n_layers)}
+        one = _attn_cache_init(cfg, batch, max_seq, dtype)
+        nd = cfg.first_dense_layers
+        cache = {"layers": _stack_cache(one, cfg.n_layers - nd)}
+        if nd:
+            cache["dense_layers"] = _stack_cache(one, nd)
+        if cfg.family == "moe":
+            cache["moe_counts"] = jnp.zeros((len(moe_mod.COUNTERS),),
+                                            jnp.int32)
+        return cache
     if cfg.family == "vlm":
         n_groups = cfg.n_layers // cfg.cross_attn_every
         per_group_self = cfg.cross_attn_every - 1
@@ -732,9 +788,9 @@ def prefill(params, cfg, tokens, extra=None, cache=None):
                                                  "cross": cross}
     x = _embed(params, cfg, tokens)
     if cfg.family in ("dense", "moe", "vlm"):
-        x, new_l = _decoder_stack(params, cfg, x, positions=positions,
-                                  caches=cache["layers"], cross_states=extra)
-        return _logits(params, cfg, x), {"layers": new_l}
+        x, new_cache, _ = _decoder_stack(params, cfg, x, positions=positions,
+                                         cache=cache, cross_states=extra)
+        return _logits(params, cfg, x), new_cache
     if cfg.family == "ssm":
         x, new_l = _rwkv_stack(params, cfg, x, caches=cache["layers"])
         return _logits(params, cfg, x), {"layers": new_l}
@@ -760,9 +816,9 @@ def decode_step(params, cfg, token, cache, extra=None):
             return _whisper_logits(params, cfg, x), {
                 "layers": new_l, "cross": cache["cross"]}
         x = _embed(params, cfg, token)
-        x, new_l = _decoder_stack(params, cfg, x, positions=positions,
-                                  caches=cache["layers"], cross_states=extra)
-        return _logits(params, cfg, x), {"layers": new_l}
+        x, new_cache, _ = _decoder_stack(params, cfg, x, positions=positions,
+                                         cache=cache, cross_states=extra)
+        return _logits(params, cfg, x), new_cache
     x = _embed(params, cfg, token)
     if cfg.family == "ssm":
         x, new_l = _rwkv_stack(params, cfg, x, caches=cache["layers"])

@@ -67,6 +67,7 @@ class _EngineCohortState:
     generated: list           # appended (B, 1) token arrays
     steps_done: int = 0
     t0: float = 0.0
+    moe_counts: dict | None = None  # the cohort's expert-layer counters
 
 
 class EngineStepper:
@@ -137,10 +138,16 @@ class EngineStepper:
         from repro.core import synthesis
         from repro.core.backends import canonical_code
 
+        from repro.models.moe import COUNTERS
+
         b = self.backend
         per_seconds = (time.monotonic() - state.t0) / len(metas)
         sampled = np.concatenate(
             [np.asarray(t) for t in state.generated], axis=1)
+        if "moe_counts" in state.cache:
+            # summed on the device over the cohort's layers and steps
+            state.moe_counts = dict(zip(
+                COUNTERS, np.asarray(state.cache["moe_counts"]).tolist()))
         out = []
         for meta, row in zip(metas, sampled):
             text = b._detokenize(row)
@@ -171,9 +178,20 @@ class ContinuousStats:
     joined_inflight: int = 0   # requests admitted while >=1 cohort was decoding
     occupancy: int = 0         # active requests right now
     max_occupancy: int = 0     # high-water mark of concurrent active requests
+    # expert layer of a MoE model, over finished cohorts (moe.COUNTERS)
+    moe_routed: int = 0        # (token, expert) assignments routed
+    moe_to_held: int = 0       # ... of them to experts this chip holds
+    moe_held_hit: int = 0      # held experts with >=1 token, per layer-step
+    moe_held_computed: int = 0 # held experts computed, per layer-step
+    moe_dropped: int = 0       # assignments to held experts dropped
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
+
+    def add_moe(self, counts: dict) -> None:
+        for name, value in counts.items():
+            attr = f"moe_{name}"
+            setattr(self, attr, getattr(self, attr) + int(value))
 
 
 class _Req:
@@ -352,8 +370,11 @@ class ContinuousBatcher:
                         obs_trace.record_for_meta(
                             req.meta, "engine_decode", decode_s, steps=steps)
                         req.future.set_result(resp)
+                    moe_counts = getattr(cohort.state, "moe_counts", None)
                     with self._mu:
                         self.stats.completed += len(cohort.reqs)
+                        if moe_counts:
+                            self.stats.add_moe(moe_counts)
             with self._mu:
                 self.stats.occupancy = sum(
                     len(c.reqs) for c in self._cohorts)

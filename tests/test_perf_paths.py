@@ -142,6 +142,15 @@ def test_moe_a2a_subprocess():
             g = jax.jit(jax.grad(lambda x_: M.moe_apply(p, cfg2, x_).sum()))(x)
         assert float(jnp.max(jnp.abs(out - ref))) < 1e-4
         assert float(jnp.max(jnp.abs(g - g0))) < 1e-3
+        # the serving form's counters, summed over every device
+        with shd.use_sharding(mesh):
+            out_s, counts = jax.jit(lambda p_, x_: M.moe_serve(p_, cfg2, x_))(
+                p, x)
+        assert float(jnp.max(jnp.abs(out_s - ref))) < 1e-4
+        c = dict(zip(M.COUNTERS, counts.tolist()))
+        assert c["routed"] == c["to_held"] == 4 * 16 * 2, c
+        assert c["held_computed"] == 8 and 0 < c["held_hit"] <= 8, c
+        assert c["dropped"] == 0, c
         print("OK a2a")
     """)
     env = dict(os.environ)
